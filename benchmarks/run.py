@@ -24,6 +24,8 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
     only = [s.strip() for s in args.only.split(",") if s.strip()]
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -1,9 +1,10 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms (seconds), per device, TPU v5e constants:
-  compute    = HLO_FLOPs / peak_FLOPs            (197 TFLOP/s bf16 / chip)
-  memory     = HLO_bytes / HBM_bw                (819 GB/s / chip)
-  collective = collective_bytes / link_bw        (~50 GB/s / ICI link)
+Three terms (seconds), per device, from the peaks of the chip named by
+its ``device_kind`` (``PEAKS``):
+  compute    = HLO_FLOPs / peak_FLOPs
+  memory     = HLO_bytes / HBM_bw
+  collective = collective_bytes / link_bw
 
 ``cost_analysis()`` FLOPs/bytes on a post-SPMD module are already
 per-device. Collective bytes are not in cost_analysis: we parse the
@@ -16,9 +17,22 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / ICI link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of one chip; a kind not in ``PEAKS`` is an error."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -70,21 +84,22 @@ class Roofline:
     flops: float                 # per device (loop-aware HLO dot count)
     hbm_bytes: float             # per device (analytic model)
     coll_bytes: float            # per device (weighted, loop-aware HLO)
+    device_kind: str             # key into PEAKS
     model_flops: float = 0.0     # 6*N*D (useful compute, global)
     chips: int = 256
     hbm_bytes_hlo: float = 0.0   # fusion-naive HLO upper bound (recorded)
 
     @property
     def t_compute(self):
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind)["bf16_flops"]
 
     @property
     def t_memory(self):
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks(self.device_kind)["hbm_bw"]
 
     @property
     def t_collective(self):
-        return self.coll_bytes / LINK_BW
+        return self.coll_bytes / peaks(self.device_kind)["link_bw"]
 
     @property
     def bottleneck(self):
@@ -190,7 +205,7 @@ def _cache_bytes(cfg, shape, dp) -> float:
 
 
 def analyze(cost: dict, hlo_text: str, cfg, shape, chips: int,
-            experts_2d: bool = False) -> Roofline:
+            device_kind: str, experts_2d: bool = False) -> Roofline:
     """Roofline terms from the compiled per-device HLO.
 
     Uses the loop-aware walker in ``hlo_analyzer`` (XLA's cost_analysis
@@ -207,6 +222,7 @@ def analyze(cost: dict, hlo_text: str, cfg, shape, chips: int,
                                      data_shards=16, pods=pods,
                                      experts_2d=experts_2d),
         coll_bytes=weighted_collective_bytes(t.coll),
+        device_kind=device_kind,
         model_flops=model_flops(cfg, shape),
         chips=chips,
         hbm_bytes_hlo=t.bytes,

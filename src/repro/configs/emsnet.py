@@ -33,19 +33,20 @@ class EMSNetConfig:
     dropout: float = 0.0
     dtype: str = "float32"
     # text-attention backend: route _bert_block through the Pallas
-    # flash kernel (key-padding-masked). flash_interpret=True runs the
-    # kernel body on CPU (this container); set False on real TPUs.
+    # flash kernel (key-padding-masked). Whether the kernel is
+    # interpreted is decided by kernels.ops from the backend.
     use_flash_text: bool = False
-    flash_interpret: bool = True
     # ragged text attention: flash_segments routes the *natural* (B, S)
     # path through the segment-masked flash kernel at the same fixed
     # flash_block the packed ragged layout uses. Fixed per-block
     # reduction shapes make a packed ragged call bit-identical to the
-    # per-row reference, so a bit-parity (atol 0) reference config must
-    # set use_flash_text=True, flash_segments=True with the same
-    # flash_block as the ragged engine.
+    # per-row reference on XLA-CPU, so a bit-parity (atol 0) reference
+    # config must set use_flash_text=True, flash_segments=True with the
+    # same flash_block as the ragged engine's ragged_align.
+    # flash_block is the segment kernel's block on both axes; the TPU
+    # compiler needs its key block to be a multiple of 128.
     flash_segments: bool = False
-    flash_block: int = 8
+    flash_block: int = 128
 
     @property
     def text_dims(self) -> Tuple[int, int, int, int]:

@@ -44,14 +44,9 @@ def axis_sizes(mesh):
 
 
 def abstract_mesh(shape, axes):
-    """Device-free mesh for spec construction/testing, across the
-    AbstractMesh signature change: older jax takes ``(shape, axis_names)``
-    positionally; 0.4.35+ takes one ``((name, size), ...)`` tuple."""
+    """Device-free mesh for spec construction/testing."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(shape, axes)
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 class Policy:

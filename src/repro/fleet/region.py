@@ -3,15 +3,13 @@
 The fleet story in one file:
 
   * **One spec, N replicas** — ``RegionSim`` builds every replica with
-    the same ``build_engine`` spec string over the same (mesh-placed)
-    parameter pytree. ``fleet_mesh``/``place_fleet_params`` activate the
-    dormant ``distributed/sharding.py`` policy: a ``('data','model')``
-    device mesh over the available jax devices (CI emulates N host
-    devices with ``XLA_FLAGS=--xla_force_host_platform_device_count``)
-    and the policy's PartitionSpecs placed with ``jax.device_put`` — the
-    EMSNet pytree has no tensor-parallel names, so every leaf lands
-    replicated across the mesh: one weight copy per device, which *is*
-    the replica story. Pytree identity is preserved, so the
+    the same ``build_engine`` spec string. With ``devices`` each replica
+    owns one device: its parameters are placed there
+    (``place_replica_params``) and its flushes run there. Without it
+    every replica shares the pytree it is given, for example one that
+    ``fleet_mesh``/``place_fleet_params`` replicated over a
+    ``('data','model')`` mesh through the ``distributed/sharding.py``
+    policy. Pytree identity is preserved either way, so the
     ``share_encoders`` grouped-tail fast path keeps working.
 
   * **Shared simulated clock** — arrivals are replayed in global fleet
@@ -59,7 +57,8 @@ from ..serving.api import build_engine
 from .admission import AdmitAll
 from .workload import IncidentSession, merge_sessions
 
-__all__ = ["fleet_mesh", "place_fleet_params", "ConsistentHashRouter",
+__all__ = ["fleet_mesh", "place_fleet_params", "place_replica_params",
+           "ConsistentHashRouter",
            "DegradedRecord", "GlassShedPath", "RegionSim"]
 
 
@@ -116,6 +115,17 @@ def place_fleet_params(params: Dict[str, dict], mesh, *, cfg=None,
         "param_bytes": int(nbytes),
     }
     return {k: placed_by_id[id(v)] for k, v in params.items()}, report
+
+
+def place_replica_params(params: Dict[str, dict], device):
+    """Commit the engine parameter pytrees to one ``device``. Names
+    sharing ONE pytree (the subset zoo) are placed once and keep
+    identity, as in ``place_fleet_params``."""
+    placed_by_id: Dict[int, dict] = {}
+    for p in params.values():
+        if id(p) not in placed_by_id:
+            placed_by_id[id(p)] = jax.device_put(p, device)
+    return {k: placed_by_id[id(v)] for k, v in params.items()}
 
 
 # ---------------------------------------------------------------- router
@@ -271,15 +281,22 @@ class RegionSim:
     serving clock (flush start = ``max(clock, oldest pending arrival)``,
     flush cost = measured wall seconds of the real XLA calls). Shed
     sessions go to the ``GlassShedPath``. Nothing is ever dropped:
-    ``sessions_offered == admitted + shed`` is an invariant."""
+    ``sessions_offered == admitted + shed`` is an invariant.
+
+    ``devices`` (one per replica) gives replica ``r`` its own copy of
+    ``params`` on ``devices[r]``, so its flushes run on that device."""
 
     def __init__(self, models, params, *, n_replicas: int = 2,
                  spec: str = "batch+stream", admission=None,
                  profile=None, router: Optional[ConsistentHashRouter] = None,
                  tracer=None, svc_prior_s: float = 0.002,
-                 engine_kw: Optional[dict] = None):
+                 engine_kw: Optional[dict] = None,
+                 devices: Optional[Sequence] = None):
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
+        if devices is not None and len(devices) != n_replicas:
+            raise ValueError(f"{len(devices)} devices for "
+                             f"{n_replicas} replicas")
         self.n_replicas = n_replicas
         self.admission = admission if admission is not None else AdmitAll()
         self.router = (router if router is not None
@@ -292,9 +309,11 @@ class RegionSim:
         self.tracer = tracer
         kw = dict(share_encoders=True, deadline_s=None)
         kw.update(engine_kw or {})
-        self.replicas = [build_engine(models, params, spec,
-                                      tracer=tracer, **kw)
-                         for _ in range(n_replicas)]
+        replica_params = ([place_replica_params(params, d)
+                           for d in devices] if devices is not None
+                          else [params] * n_replicas)
+        self.replicas = [build_engine(models, rp, spec, tracer=tracer, **kw)
+                         for rp in replica_params]
         self.glass = (GlassShedPath(models, params, profile,
                                     bucketer=kw.get("bucketer"),
                                     metrics=self.metrics, tracer=tracer)

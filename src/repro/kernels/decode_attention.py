@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -106,7 +105,7 @@ def decode_attention(q, kbuf, vbuf, slot_pos, t, *, window=0, scale=None,
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, Dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(t_arr, qr, kr, vr, pos)

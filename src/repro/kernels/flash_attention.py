@@ -15,7 +15,12 @@ masking. TPU-native layout decisions:
     EXPERIMENTS.md §Perf).
 
 Validated in interpret mode on CPU against ``ref.attention_ref``; the
-TPU path is the same `pl.pallas_call` with interpret=False.
+TPU path is the same `pl.pallas_call` with interpret=False. The masking
+operands use tiling-legal TPU layouts: per-row key counts ride in SMEM
+as a scalar-prefetch operand, and segment ids are read through a
+``(B, S, 1)`` query view and a ``(B, 1, S)`` key view (a key block is
+then ``(1, 1, bk)``, which the TPU compiler accepts for ``bk % 128 ==
+0``).
 """
 from __future__ import annotations
 
@@ -27,21 +32,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, *refs,
-            scale, causal, window, bq, bk, seq_k, n_kv_blocks, q_offset,
-            has_lengths, has_segments):
+def _kernel(*refs, scale, causal, window, bq, bk, seq_k, n_kv_blocks,
+            q_offset, heads, has_lengths, has_segments):
+    len_ref = sq_ref = sk_ref = None
+    if has_lengths:
+        len_ref, *refs = refs
+    q_ref, k_ref, v_ref, *refs = refs
     if has_segments:
-        sq_ref, sk_ref, o_ref, m_ref, l_ref, acc_ref = refs
-        len_ref = None
-    elif has_lengths:
-        len_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        len_ref, (o_ref, m_ref, l_ref, acc_ref) = None, refs
+        sq_ref, sk_ref, *refs = refs
+    o_ref, m_ref, l_ref, acc_ref = refs
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -61,15 +64,16 @@ def _kernel(q_ref, k_ref, v_ref, *refs,
         # segment as the query; padding carries segment id -1 and is
         # never equal to a valid id, so block/tail padding and foreign
         # rows mask out identically. A fully-masked q row outputs 0.
-        sq = sq_ref[0]                                # (bq,) int32
-        sk = sk_ref[0]                                # (bk,) int32
-        mask = (sq[:, None] == sk[None, :]) & (sk[None, :] >= 0)
+        sq = sq_ref[0]                                # (bq, 1) int32
+        sk = sk_ref[0]                                # (1, bk) int32
+        mask = (sq == sk) & (sk >= 0)
     else:
         q_pos = (q_offset + qi * bq
                  + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
         k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         # kv padding: block padding, or the row's true key count
-        mask = k_pos < (len_ref[0, 0] if has_lengths else seq_k)
+        mask = k_pos < (len_ref[pl.program_id(0) // heads] if has_lengths
+                        else seq_k)
         if causal:
             mask &= k_pos <= q_pos
         if window:
@@ -149,17 +153,21 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
     nq = (Sq + pq) // bq
     nk = (Sk + pk) // bk
 
-    def kv_index(bh, qi, ki):
+    # index maps take (bh, qi, ki) plus the scalar-prefetch ref, if any
+    def q_index(bh, qi, ki, *_):
+        return (bh, qi, 0)
+
+    def kv_index(bh, qi, ki, *_):
         return ((bh // H) * KV + (bh % H) // G, ki, 0)
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window, bq=bq, bk=bk,
         seq_k=Sk, n_kv_blocks=nk, q_offset=(Sk - Sq) if causal else 0,
-        has_lengths=kv_lengths is not None,
+        heads=H, has_lengths=kv_lengths is not None,
         has_segments=segment_ids is not None)
 
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0)),
+        pl.BlockSpec((1, bq, D), q_index),
         pl.BlockSpec((1, bk, D), kv_index),
         pl.BlockSpec((1, bk, Dv), kv_index),
     ]
@@ -168,31 +176,33 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
         seg = segment_ids.astype(jnp.int32)
         if pk:
             seg = jnp.pad(seg, ((0, 0), (0, pk)), constant_values=-1)
-        # the same (B, S) id array feeds two views: the query block and
-        # the key block of each grid step
-        in_specs.append(pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh // H, qi)))
-        in_specs.append(pl.BlockSpec((1, bk), lambda bh, qi, ki: (bh // H, ki)))
-        operands.extend([seg, seg])
-    elif kv_lengths is not None:
-        # one (1, 1) scalar block per (batch, head) program
-        lr = jnp.repeat(kv_lengths.astype(jnp.int32), H)[:, None]
-        in_specs.append(pl.BlockSpec((1, 1), lambda bh, qi, ki: (bh, 0)))
-        operands.append(lr)
+        # one (B, S) id array, two views: a query column and a key row
+        in_specs.append(pl.BlockSpec(
+            (1, bq, 1), lambda bh, qi, ki, *_: (bh // H, qi, 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bk), lambda bh, qi, ki, *_: (bh // H, 0, ki)))
+        operands.extend([seg[:, :, None], seg[:, None, :]])
+    prefetch = []
+    if kv_lengths is not None:
+        # (B,) key counts in SMEM, read per (batch, head) program
+        prefetch = [kv_lengths.astype(jnp.int32)]
 
     out = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, Dv), lambda bh, qi, ki: (bh, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B * H, nq, nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bq, Dv), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq + pq, Dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, *operands)
     out = out[:, :Sq].reshape(B, H, Sq, Dv)
     return jnp.moveaxis(out, 1, 2)

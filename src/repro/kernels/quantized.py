@@ -32,8 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 # int32 accumulator headroom: K * 127 * 127 must stay below 2^31
 MAX_K = (1 << 31) // (127 * 127)
@@ -75,7 +75,7 @@ def quantize_rowwise(x, *, block_m: int = 32, interpret: bool = False):
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((xp.shape[0], K), jnp.int8),
                    jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xp)
@@ -101,7 +101,7 @@ def dequantize_rowwise(q, scale, *, block_m: int = 32,
                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], K), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(qp, sp)
@@ -152,7 +152,7 @@ def int8_matmul(xq, sx, wq, sw, *, block_m: int = 32, block_n: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], wp.shape[1]),
                                        jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xp, wp, sxp, swp)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sf_ref,
             state, *, bt, n_chunks):
@@ -99,7 +97,7 @@ def rwkv6_scan(r, k, v, w, u, s0=None, *, block_t=64, interpret=False):
             jax.ShapeDtypeStruct((B * H, n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rr, kk, vv, ww, ur, s0r)

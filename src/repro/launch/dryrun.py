@@ -34,6 +34,8 @@ from repro.distributed.sharding import Policy
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
 
+# the chip the production meshes model (CPU-emulated devices here)
+TARGET_KIND = "TPU v5 lite"
 ARTIFACTS = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts" / "dryrun"
 
 
@@ -78,6 +80,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, *, save=True,
         rec["collectives_bytes"] = totals.coll
         rec["collectives_count"] = totals.coll_count
         roof = RL.analyze(rec["cost"], hlo, cfg, shape, chips,
+                          TARGET_KIND,
                           experts_2d=tuned and policy.experts_2d)
         rec["roofline"] = roof.as_dict()
         rec["param_count"] = cfg.param_count()

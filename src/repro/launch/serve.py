@@ -32,11 +32,12 @@ deadline flushes from a monotonic clock
 (``serving.event_loop.WallClockDriver``); ``--speed`` fast-forwards.
 
 ``--fleet RATE --replicas N`` runs the region simulator instead
-(``repro.fleet``): N engine replicas from one spec over mesh-placed
-parameters, open-loop Poisson incident arrivals at RATE sessions/s,
-consistent-hash routing, deadline admission control with on-glass
-shedding. ``--metrics-out`` writes Prometheus text; ``--trace x.jsonl``
-streams a bounded-memory audit trace:
+(``repro.fleet``): N engine replicas from one spec, each with its
+parameters on a device of its own where there are enough, open-loop
+Poisson incident arrivals at RATE sessions/s, consistent-hash routing,
+deadline admission control with on-glass shedding. ``--metrics-out``
+writes Prometheus text; ``--trace x.jsonl`` streams a bounded-memory
+audit trace:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=2 \
       PYTHONPATH=src python -m repro.launch.serve --fleet 4 \
@@ -363,19 +364,20 @@ def serve_unified(args):
 
 def serve_fleet(args):
     """``--fleet RATE``: open-loop region simulation — ``--replicas N``
-    engine replicas built from ONE spec over mesh-placed parameters,
+    engine replicas built from ONE spec, replica r on device
+    ``r % len(jax.devices())``,
     Poisson session arrivals at RATE sessions/s, consistent-hash +
     least-loaded routing, deadline admission control, and the on-glass
     degraded shed path for what the region turns away."""
     from repro.configs.emsnet import config as emsnet_config
     from repro.core import ProfileTable, profile
     from repro.fleet import (AdmissionController, AdmissionPolicy,
-                             RegionSim, fleet_mesh, generate_workload,
-                             place_fleet_params)
+                             RegionSim, generate_workload)
 
     cfg = emsnet_config(text_encoder=args.text_encoder, vocab_size=2048)
     splits, params = build_zoo(cfg)
-    placed, placement = place_fleet_params(params, fleet_mesh())
+    devs = jax.devices()
+    devices = [devs[r % len(devs)] for r in range(args.replicas)]
     payloads = sample_payloads(cfg)
     payload_fn = lambda sid, ev: payloads[ev.modality]  # noqa: E731
 
@@ -386,19 +388,17 @@ def serve_fleet(args):
                   if args.trace.endswith(".jsonl") else Tracer())
 
     full = splits["text+vitals+scene"]
-    base = profile(full, placed["text+vitals+scene"], payloads, iters=2)
+    base = profile(full, params["text+vitals+scene"], payloads, iters=2)
     deadline = (args.deadline_ms / 1e3) if args.deadline_ms else 0.5
     ctrl = AdmissionController(AdmissionPolicy(deadline_s=deadline),
                               args.replicas)
-    sim = RegionSim(splits, placed, n_replicas=args.replicas,
+    sim = RegionSim(splits, params, n_replicas=args.replicas,
                     admission=ctrl, profile=ProfileTable(base=base),
-                    tracer=tracer)
-    axes = "x".join(str(v) for v in placement["axis_sizes"].values())
-    print(f"fleet: {args.replicas} replicas, params on {axes} mesh over "
-          f"{placement['devices']} device(s) "
-          f"({placement['replicated_leaves']} replicated / "
-          f"{placement['sharded_leaves']} sharded leaves, "
-          f"{placement['param_bytes'] / 1e6:.1f} MB), "
+                    tracer=tracer, devices=devices)
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(
+        params["text+vitals+scene"]))
+    print(f"fleet: {args.replicas} replicas over {len(set(devices))} "
+          f"device(s), {nbytes / 1e6:.1f} MB of params per replica, "
           f"admission deadline {deadline * 1e3:.0f} ms")
 
     horizon = args.sessions / args.fleet
@@ -540,9 +540,9 @@ def main():
                          "admission control; --sessions N is the total "
                          "offered count")
     ap.add_argument("--replicas", type=int, default=2, metavar="N",
-                    help="--fleet: engine replicas (params are placed "
-                         "across the jax device mesh; emulate devices "
-                         "with XLA_FLAGS="
+                    help="--fleet: engine replicas, replica r on jax "
+                         "device r mod the device count (emulate "
+                         "devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
     ap.add_argument("--metrics-out", default="", metavar="PATH",
                     help="write the run's metrics registry as "
@@ -628,4 +628,7 @@ def main():
 
 
 if __name__ == "__main__":
+    # the entry point owns the compile cache; tests call main() without it
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.emsnet import EMSNetConfig
+from repro.kernels import ops
 from . import layers as L
 
 
@@ -55,40 +56,37 @@ def text_encoder_init(key, cfg: EMSNetConfig):
     }
 
 
-def _bert_block(p, x, mask, heads, *, flash=None, segments=None):
-    """``flash=(kv_lengths, interpret)`` routes attention through the
-    Pallas flash kernel (key-padding-masked, non-causal); None keeps the
+def _bert_block(p, x, mask, heads, *, kv_lengths=None, segments=None):
+    """``kv_lengths`` (B,) int32 routes attention through the Pallas
+    flash kernel (key-padding-masked, non-causal); None keeps the
     materialized einsum path. Both see the same qkv/wo projections.
 
-    ``segments=(seg_ids, use_flash, block, interpret)`` is the ragged
-    layout: ``seg_ids`` (B, S) int32 gives each position's row id (-1 =
+    ``segments=(seg_ids, use_flash, block)`` is the ragged layout:
+    ``seg_ids`` (B, S) int32 gives each position's row id (-1 =
     padding); a query attends a key iff their ids match. With
     ``use_flash`` the segment-masked flash kernel runs at the fixed
-    ``block`` size (the bit-parity path); otherwise a materialized
-    pairwise mask feeds the einsum path."""
+    ``block`` size; otherwise a materialized pairwise mask feeds the
+    einsum path."""
     B, S, d = x.shape
     hd = d // heads
     h = L.layernorm(p["ln1"], x)
     qkv = L.dense(p["wqkv"], h).reshape(B, S, 3, heads, hd)
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     if segments is not None:
-        seg, use_flash, block, interpret = segments
+        seg, use_flash, block = segments
         if use_flash:
-            from repro.kernels.flash_attention import flash_attention
-            att = flash_attention(q, k, v, causal=False, segment_ids=seg,
-                                  block_q=block, block_k=block,
-                                  interpret=interpret).reshape(B, S, d)
+            att = ops.flash_attention(q, k, v, causal=False, segment_ids=seg,
+                                      block_q=block,
+                                      block_k=block).reshape(B, S, d)
         else:
             pair = (seg[:, :, None] == seg[:, None, :]) & (seg >= 0)[:, None, :]
             s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
             s = jnp.where(pair[:, None], s, -1e30)
             w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
             att = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, S, d)
-    elif flash is not None:
-        from repro.kernels.flash_attention import flash_attention
-        kv_lengths, interpret = flash
-        att = flash_attention(q, k, v, causal=False, kv_lengths=kv_lengths,
-                              interpret=interpret).reshape(B, S, d)
+    elif kv_lengths is not None:
+        att = ops.flash_attention(q, k, v, causal=False,
+                                  kv_lengths=kv_lengths).reshape(B, S, d)
     else:
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
         s = jnp.where(mask[:, None, None, :], s, -1e30)
@@ -116,7 +114,6 @@ def text_encoder(p, cfg: EMSNetConfig, tokens):
     if isinstance(tokens, dict):
         return _text_encoder_ragged(p, cfg, tokens)
     _, d, heads, _ = cfg.text_dims
-    flash = segments = None
     if cfg.use_flash_text and cfg.flash_segments:
         # pad S to a flash_block multiple: every GEMM then has M >= block
         # like the packed layout (an M=1 row would lower to a
@@ -126,7 +123,7 @@ def text_encoder(p, cfg: EMSNetConfig, tokens):
         tokens = jnp.pad(tokens, ((0, 0), (0, Sp - tokens.shape[1])))
         mask = tokens > 0
         seg = jnp.where(mask, 0, -1).astype(jnp.int32)
-        segments = (seg, True, b, cfg.flash_interpret)
+        segments = (seg, True, b)
         pos = jnp.minimum(jnp.arange(Sp), cfg.max_text_len - 1)
         x = L.embed(p["tok"], tokens) + p["pos"]["emb"][pos][None]
         for blk in p["blocks"]:
@@ -136,11 +133,11 @@ def text_encoder(p, cfg: EMSNetConfig, tokens):
         return (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
     mask = tokens > 0
     S = tokens.shape[1]
-    if cfg.use_flash_text:
-        flash = (mask.sum(-1).astype(jnp.int32), cfg.flash_interpret)
+    kv_lengths = (mask.sum(-1).astype(jnp.int32) if cfg.use_flash_text
+                  else None)
     x = L.embed(p["tok"], tokens) + p["pos"]["emb"][None, :S]
     for blk in p["blocks"]:
-        x = _bert_block(blk, x, mask, heads, flash=flash, segments=segments)
+        x = _bert_block(blk, x, mask, heads, kv_lengths=kv_lengths)
     x = L.layernorm(p["ln"], x)
     m = mask[..., None].astype(x.dtype)
     return (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
@@ -162,7 +159,7 @@ def _text_encoder_ragged(p, cfg: EMSNetConfig, packed):
     seg = packed["row_ids"][None, :]                # (1, T)
     T = toks.shape[1]
     mask = seg >= 0
-    segments = (seg, cfg.use_flash_text, cfg.flash_block, cfg.flash_interpret)
+    segments = (seg, cfg.use_flash_text, cfg.flash_block)
     x = L.embed(p["tok"], toks) + p["pos"]["emb"][packed["pos"]][None]
     for blk in p["blocks"]:
         x = _bert_block(blk, x, mask, heads, segments=segments)

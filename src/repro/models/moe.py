@@ -203,18 +203,8 @@ def moe_forward_a2a(p, x, cfg, *, mesh, token_axes, expert_axes,
     exp_spec = P(expert_axes, None, None)
     in_specs = (tok_spec, P(None, None), exp_spec, exp_spec, exp_spec)
     out_specs = (tok_spec, P())
-    if hasattr(jax, "shard_map"):
-        try:
-            sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-        except TypeError:
-            # ~0.5-0.6 band: public jax.shard_map, pre-rename kwarg
-            sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-    else:   # pre-promotion spelling
-        from jax.experimental.shard_map import shard_map as _shard_map
-        sm = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     xf = x.reshape(B * S, d)
     y, aux = sm(xf, p["router"], p["gate"], p["up"], p["down"])
     y = y.reshape(B, S, d)

@@ -12,7 +12,7 @@ factory:
     ``ragged=True`` (default OFF) upgrades the flush to the
     concatenated ragged layout: ``core.bucketing.RaggedBatch`` packs
     every pending row of a variable-length modality into one buffer
-    (text at ``flash_block``-aligned offsets under the segment-masked
+    (text at ``ragged_align``-aligned offsets under the segment-masked
     flash kernel; vitals back-to-back with per-row state resets), and
     all pending fusion tails — across sessions AND modality subsets —
     run as ONE grouped call through zero-filled full-set heads (subset

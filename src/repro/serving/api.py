@@ -324,9 +324,10 @@ class BatchPolicy:
     call across all pending sessions and modality subsets (possible
     when the zoo shares one parameter pytree — ``share_encoders`` zoos;
     engines with per-model parameters keep the per-model tail loop).
-    ``ragged_align`` must equal the model's text ``flash_block`` (packed
-    rows start on flash-block boundaries — the bit-parity requirement);
-    bit parity against the unbucketed reference additionally needs the
+    ``ragged_align`` is the packed rows' start alignment. Any alignment
+    gives the same masked attention; XLA-CPU bit parity against the
+    unbucketed reference needs it equal to the model's text
+    ``flash_block`` (rows start on flash-block boundaries) and the
     model config run with ``use_flash_text=True, flash_segments=True``
     on both sides. Defaults OFF: the bucketed path stays the default
     fast path."""

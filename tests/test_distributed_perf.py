@@ -165,3 +165,17 @@ def test_analyzer_on_real_compiled_module():
     comp = jax.jit(f).lower(jnp.zeros((L, D, D)), jnp.zeros((B, D))).compile()
     t = H.analyze_hlo(comp.as_text())
     assert t.flops == pytest.approx(L * 2 * B * D * D)
+
+
+def test_roofline_peaks_by_device_kind():
+    """Roofline terms divide by the named chip's published peaks; a kind
+    with no entry raises instead of borrowing another chip's peaks."""
+    from repro.analysis import roofline as RL
+    r = RL.Roofline(flops=197e12, hbm_bytes=819e9, coll_bytes=100e9,
+                    device_kind="TPU v5 lite")
+    assert (r.t_compute, r.t_memory, r.t_collective) == pytest.approx(
+        (1.0, 1.0, 2.0))
+    assert r.bottleneck == "collective"
+    with pytest.raises(ValueError, match="no published peaks"):
+        RL.Roofline(flops=1.0, hbm_bytes=1.0, coll_bytes=1.0,
+                    device_kind="cpu").t_compute

@@ -298,6 +298,36 @@ def test_region_sim_conservation_and_bit_parity(fleet_models):
     assert f"emsserve_fleet_ttfp_s_count {n}" in prom
 
 
+def test_region_sim_replica_devices(fleet_models):
+    """``devices`` gives every replica its own committed copy of the
+    params (one pytree per replica, shared across its zoo keys), and the
+    finals equal those of replicas that share one pytree."""
+    _, splits, shared, placed, _, payloads = fleet_models
+    sessions = generate_workload(3.0, 2.0, seed=0, time_scale=0.2)
+    dev = jax.devices()[0]
+    with pytest.raises(ValueError, match="devices for"):
+        RegionSim(splits, placed, n_replicas=2, devices=[dev])
+    sim = RegionSim(splits, {k: shared for k in placed}, n_replicas=2,
+                    engine_kw=dict(ENGINE_KW), devices=[dev, dev])
+    per_replica = [e.params for e in sim.replicas]
+    for p in per_replica:
+        assert len({id(v) for v in p.values()}) == 1
+        leaf = jax.tree.leaves(next(iter(p.values())))[0]
+        assert leaf.committed and leaf.devices() == {dev}
+    assert per_replica[0]["text"] is not per_replica[1]["text"]
+    base = RegionSim(splits, placed, n_replicas=2,
+                     engine_kw=dict(ENGINE_KW))
+    payload_fn = lambda sid, ev: payloads[ev.modality]  # noqa: E731
+    sim.run(sessions, payload_fn)
+    base.run(sessions, payload_fn)
+    for s in sessions:
+        got, want = sim.final_outputs(s.sid), base.final_outputs(s.sid)
+        assert got is not None and set(got) == set(want)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(want[k]))
+
+
 def test_region_sim_shed_sessions_degrade_only(fleet_models, tmp_path):
     _, splits, _, placed, _, payloads = fleet_models
     sessions = generate_workload(3.0, 2.0, seed=1, time_scale=0.2)

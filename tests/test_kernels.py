@@ -112,10 +112,10 @@ def test_rwkv6_initial_state_chunked(key):
 
 def test_ops_wrappers_jit(key):
     q = jax.random.normal(key, (1, 32, 2, 16))
-    out = ops.flash_attention(q, q, q, interpret=True)
+    out = ops.flash_attention(q, q, q)
     assert out.shape == q.shape
     r = jax.random.normal(key, (1, 16, 2, 8))
     w = jnp.full((1, 16, 2, 8), 0.9)
     u = jnp.zeros((2, 8))
-    y, s = ops.rwkv6_scan(r, r, r, w, u, interpret=True)
+    y, s = ops.rwkv6_scan(r, r, r, w, u)
     assert y.shape == r.shape and s.shape == (1, 2, 8, 8)

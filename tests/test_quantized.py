@@ -81,9 +81,9 @@ def _tiered(splits, params, *, bandwidth=5.0, **kw):
 def test_quantize_roundtrip_error_bound(key, shape):
     """Round-trip error <= scale/2 per element, per row."""
     x = jax.random.normal(key, shape) * 3.0
-    q, s = ops.quantize_rowwise(x, interpret=True)
+    q, s = ops.quantize_rowwise(x)
     assert q.dtype == jnp.int8 and s.shape == (shape[0], 1)
-    back = ops.dequantize_rowwise(q, s, interpret=True)
+    back = ops.dequantize_rowwise(q, s)
     bound = np.asarray(s) / 2.0 + 1e-7
     assert (np.abs(np.asarray(back) - np.asarray(x)) <= bound).all()
 
@@ -92,7 +92,7 @@ def test_quantize_zero_row_guard(key):
     """An all-zero row must quantize to zeros with a finite scale, not
     divide by zero."""
     x = jnp.zeros((3, 16)).at[1].set(jax.random.normal(key, (16,)))
-    q, s = ops.quantize_rowwise(x, interpret=True)
+    q, s = ops.quantize_rowwise(x)
     assert np.isfinite(np.asarray(s)).all()
     assert np.abs(np.asarray(q)[0]).max() == 0
     assert np.abs(np.asarray(q)[2]).max() == 0
@@ -103,7 +103,7 @@ def test_quantize_rowwise_matches_ref(key, shape):
     """Kernel q values match the jnp oracle exactly; scales to 1 ulp
     (jit may turn /127 into a multiply by reciprocal)."""
     x = jax.random.normal(key, shape) * 2.0
-    q, s = ops.quantize_rowwise(x, interpret=True)
+    q, s = ops.quantize_rowwise(x)
     qr, sr = ref.quantize_rowwise_ref(x)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
@@ -119,7 +119,7 @@ def test_int8_matmul_exact_vs_ref(key, shape):
     xq, sx = ref.quantize_rowwise_ref(jax.random.normal(k1, (M, K)))
     wq, sw = ref.quantize_rowwise_ref(jax.random.normal(k2, (N, K)))
     wq, sw = wq.T, sw.T                      # colwise layout (K, N), (1, N)
-    got = ops.int8_matmul(xq, sx, wq, sw, interpret=True)
+    got = ops.int8_matmul(xq, sx, wq, sw)
     want = ref.int8_matmul_ref(xq, sx, wq, sw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -133,8 +133,8 @@ def test_quantized_matmul_within_analytical_bound(key, shape):
     k1, k2 = jax.random.split(key)
     x = jax.random.normal(k1, (M, K))
     w = jax.random.normal(k2, (K, N)) / np.sqrt(K)
-    wq, sw = ops.quantize_colwise(w, interpret=True)
-    got = np.asarray(ops.quantized_matmul(x, wq, sw, interpret=True))
+    wq, sw = ops.quantize_colwise(w)
+    got = np.asarray(ops.quantized_matmul(x, wq, sw))
     want = np.asarray(x @ w)
     xn = np.asarray(x)
     w_hat = np.asarray(wq, np.float32) * np.asarray(sw)
@@ -150,8 +150,7 @@ def test_int8_matmul_k_guard():
     xq = jnp.zeros((1, K), jnp.int8)
     wq = jnp.zeros((K, 4), jnp.int8)
     with pytest.raises(ValueError, match="int32 accumulator"):
-        ops.int8_matmul(xq, jnp.ones((1, 1)), wq, jnp.ones((1, 4)),
-                        interpret=True)
+        ops.int8_matmul(xq, jnp.ones((1, 1)), wq, jnp.ones((1, 4)))
 
 
 def test_quantized_matmul_leading_dims(key):
@@ -159,10 +158,10 @@ def test_quantized_matmul_leading_dims(key):
     k1, k2 = jax.random.split(key)
     x = jax.random.normal(k1, (2, 5, 32))
     w = jax.random.normal(k2, (32, 16)) / np.sqrt(32)
-    wq, sw = ops.quantize_colwise(w, interpret=True)
-    got = ops.quantized_matmul(x, wq, sw, interpret=True)
+    wq, sw = ops.quantize_colwise(w)
+    got = ops.quantized_matmul(x, wq, sw)
     assert got.shape == (2, 5, 16)
-    flat = ops.quantized_matmul(x.reshape(10, 32), wq, sw, interpret=True)
+    flat = ops.quantized_matmul(x.reshape(10, 32), wq, sw)
     np.testing.assert_array_equal(np.asarray(got).reshape(10, 16),
                                   np.asarray(flat))
 
@@ -179,8 +178,8 @@ def test_roundtrip_property_hypothesis():
            scale=st.floats(1e-3, 1e3))
     def check(seed, m, k, scale):
         x = jax.random.normal(jax.random.PRNGKey(seed), (m, k)) * scale
-        q, s = ops.quantize_rowwise(x, interpret=True)
-        back = ops.dequantize_rowwise(q, s, interpret=True)
+        q, s = ops.quantize_rowwise(x)
+        back = ops.dequantize_rowwise(q, s)
         bound = np.asarray(s) / 2.0 * (1 + 1e-6) + 1e-12
         assert (np.abs(np.asarray(back) - np.asarray(x)) <= bound).all()
 

@@ -39,7 +39,7 @@ ALL = ("text", "vitals", "scene")
 @pytest.fixture(scope="module")
 def ragged_cfg():
     return tiny(text_encoder="microbert", use_flash_text=True,
-                flash_segments=True)
+                flash_segments=True, flash_block=8)
 
 
 @pytest.fixture(scope="module")

@@ -30,7 +30,7 @@ ALL = ("text", "vitals", "scene")
 @functools.lru_cache(maxsize=None)
 def _text_setup():
     cfg = tiny(text_encoder="microbert", use_flash_text=True,
-               flash_segments=True)
+               flash_segments=True, flash_block=8)
     p = E.init_params(cfg, jax.random.PRNGKey(0), ("text",))
     nat = jax.jit(lambda t: E.encode(p, cfg, "text", t))
     rag = jax.jit(lambda d: E.encode(p, cfg, "text", d))
