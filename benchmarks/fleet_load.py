@@ -2,8 +2,8 @@
 
 Everything before this benchmark measured a handful of closed-loop
 sessions on one host. Here the region simulator (``repro.fleet``)
-drives N engine replicas — built from ONE ``build_engine`` spec over
-mesh-placed parameters — with an open-loop Poisson session-arrival
+drives N engine replicas — built from ONE ``build_engine`` spec, each
+on a device of its own — with an open-loop Poisson session-arrival
 process: arrivals do not wait for the system, so when the offered rate
 exceeds fleet capacity the backlog (and every new session's
 time-to-first-prediction) grows without bound. Three measurements:
@@ -25,7 +25,7 @@ time-to-first-prediction) grows without bound. Three measurements:
     at-knee service level; without it the p99 blows past the knee.
 
 Bit-parity is spot-checked every run: finalized fleet sessions must
-match a per-event reference engine (same spec, same mesh-placed
+match a per-event reference engine (same spec, same parameter
 pytree, same fixed batch bucket, one flush per event) at atol 0 —
 fleet scale never buys drift. The fixed bucket (``ENGINE_KW``) is what
 makes atol 0 honest: it pins every XLA call to one program shape, the
@@ -47,9 +47,9 @@ from __future__ import annotations
 
 import os
 
-# must precede any jax import: emulate a multi-device host so the fleet
-# mesh has real devices to place parameters on (CI overrides with its
-# own XLA_FLAGS; a pre-set value is respected)
+# must precede any jax import: emulate a multi-device host so each
+# fleet replica has a device of its own (CI overrides with its own
+# XLA_FLAGS; a pre-set value is respected)
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -89,7 +89,6 @@ def _build(quick, seed=0):
     import jax
     from repro.configs.emsnet import tiny
     from repro.core import emsnet_zoo, split
-    from repro.fleet import fleet_mesh, place_fleet_params
 
     # Always the tiny config, even in full mode: this benchmark reads
     # SERVING dynamics (queueing, coalescing, admission), where the
@@ -103,12 +102,13 @@ def _build(quick, seed=0):
     splits = {k: split(m) for k, m in zoo.items()}
     shared = zoo["text+vitals+scene"].init_fn(jax.random.PRNGKey(seed))
     params = {k: shared for k in zoo}
-    mesh = fleet_mesh()
-    placed, placement = place_fleet_params(params, mesh)
     payloads = C.sample_payloads(cfg, seed=seed + 1)
     payloads["vitals"] = payloads["vitals"][:, :5]
-    C.warmup_engine_models(splits, placed, payloads)
-    return cfg, splits, placed, payloads, placement
+    C.warmup_engine_models(splits, params, payloads)
+    placement = {"devices": len(jax.devices()),
+                 "param_bytes": int(sum(x.nbytes
+                                        for x in jax.tree.leaves(shared)))}
+    return cfg, splits, params, payloads, placement
 
 
 def _profile():
@@ -117,7 +117,7 @@ def _profile():
                               "enc:scene": 0.05, "tail": 0.02, "full": 0.16})
 
 
-def _simulate(splits, placed, payloads, *, rate, n_sessions, n_replicas,
+def _simulate(splits, params, payloads, *, rate, n_sessions, n_replicas,
               admission=None, seed=0, profile=None):
     """One open-loop run at ``rate`` sessions/s, horizon sized so the
     offered-session count stays ~constant across rates (the wall cost
@@ -131,22 +131,22 @@ def _simulate(splits, placed, payloads, *, rate, n_sessions, n_replicas,
                                  time_scale=TIME_SCALE)
     if callable(admission):
         admission = admission()
-    sim = RegionSim(splits, placed, n_replicas=n_replicas,
+    sim = RegionSim(splits, params, n_replicas=n_replicas,
                     admission=admission, profile=profile,
                     engine_kw=dict(ENGINE_KW))
     sim.run(sessions, lambda sid, ev: payloads[ev.modality])
     return sim, sessions
 
 
-def _point(splits, placed, payloads, **kw):
+def _point(splits, params, payloads, **kw):
     """Warm-then-measure: the first pass compiles every bucketed batch
     shape this exact workload hits (pow2 row padding means intermediate
     coalesce sizes are distinct XLA programs — a compile landing inside
     a measured flush would poison that point's p99); the second pass
     replays the byte-identical workload on warm engines and is the one
     reported."""
-    _simulate(splits, placed, payloads, **kw)
-    return _simulate(splits, placed, payloads, **kw)
+    _simulate(splits, params, payloads, **kw)
+    return _simulate(splits, params, payloads, **kw)
 
 
 def _ttfp_stats(sim):
@@ -159,15 +159,15 @@ def _ttfp_stats(sim):
             "p99_s": float(np.percentile(xs, 99))}
 
 
-def _measure_mu(splits, placed, payloads, *, rate, n_sessions, seed):
-    sim, _ = _simulate(splits, placed, payloads, rate=rate,
+def _measure_mu(splits, params, payloads, *, rate, n_sessions, seed):
+    sim, _ = _simulate(splits, params, payloads, rate=rate,
                        n_sessions=n_sessions, n_replicas=1, seed=seed)
     busy = sum(done - start for _, start, done, _ in sim.flush_log)
     events = sum(n for _, _, _, n in sim.flush_log)
     return (events / busy if busy > 0 else 1.0), sim._svc_est
 
 
-def _calibrate(splits, placed, payloads, *, n_sessions, seed):
+def _calibrate(splits, params, payloads, *, n_sessions, seed):
     """Per-replica capacity in sessions/s, measured twice on one
     replica as admitted events over summed flush wall seconds:
 
@@ -182,9 +182,9 @@ def _calibrate(splits, placed, payloads, *, n_sessions, seed):
       against THIS rate — coalescing is self-balancing (more backlog ->
       bigger batches -> higher throughput), so only rates above the
       saturated ceiling queue to death."""
-    mu_light, svc_light = _measure_mu(splits, placed, payloads, rate=4.0,
+    mu_light, svc_light = _measure_mu(splits, params, payloads, rate=4.0,
                                       n_sessions=n_sessions, seed=seed)
-    mu_sat, _ = _measure_mu(splits, placed, payloads, rate=200.0,
+    mu_sat, _ = _measure_mu(splits, params, payloads, rate=200.0,
                             n_sessions=n_sessions, seed=seed)
     return {"service_rate_light_events_per_s": mu_light,
             "service_rate_saturated_events_per_s": mu_sat,
@@ -193,7 +193,7 @@ def _calibrate(splits, placed, payloads, *, n_sessions, seed):
             "capacity_saturated_sessions_per_s": mu_sat / EVENTS_PER_SESSION}
 
 
-def _parity_check(sim, sessions, splits, placed, payloads, *, limit=4):
+def _parity_check(sim, sessions, splits, params, payloads, *, limit=4):
     """Finalized fleet sessions vs a per-event reference engine — same
     spec and the same FIXED batch bucket (``ENGINE_KW``), driven one
     flush per event — at atol 0. Equal bucket on both sides is load-
@@ -208,7 +208,7 @@ def _parity_check(sim, sessions, splits, placed, payloads, *, limit=4):
         got = sim.final_outputs(s.sid)
         if got is None:
             continue
-        ref = build_engine(splits, placed, "batch+stream",
+        ref = build_engine(splits, params, "batch+stream",
                            share_encoders=True, deadline_s=None,
                            **ENGINE_KW)
         preds = []
@@ -227,7 +227,7 @@ def _parity_check(sim, sessions, splits, placed, payloads, *, limit=4):
 def run(quick=True, *, smoke=False, seed=0):
     from repro.fleet import AdmissionController, AdmissionPolicy
 
-    cfg, splits, placed, payloads, placement = _build(quick or smoke,
+    cfg, splits, params, payloads, placement = _build(quick or smoke,
                                                       seed=seed)
     # offered sessions per sweep point: the overload points must offer
     # enough work for the backlog to integrate well past the light-load
@@ -238,13 +238,13 @@ def run(quick=True, *, smoke=False, seed=0):
     # ---- warmup passes: compile every engine path the sims will hit,
     # spaced arrivals (single-event flushes) AND a burst (coalesced
     # bucketed batch shapes)
-    _simulate(splits, placed, payloads, rate=2.0, n_sessions=6,
+    _simulate(splits, params, payloads, rate=2.0, n_sessions=6,
               n_replicas=1, seed=seed)
-    _simulate(splits, placed, payloads, rate=200.0, n_sessions=n_point,
+    _simulate(splits, params, payloads, rate=200.0, n_sessions=n_point,
               n_replicas=1, seed=seed)
 
     # ---- capacity calibration ---------------------------------------
-    cal = _calibrate(splits, placed, payloads, n_sessions=n_point,
+    cal = _calibrate(splits, params, payloads, n_sessions=n_point,
                      seed=seed)
     cap = cal["capacity_saturated_sessions_per_s"]
 
@@ -266,7 +266,7 @@ def run(quick=True, *, smoke=False, seed=0):
         # drain at one horizon and flatten the knee away; the extra
         # sessions are nearly free there (max coalescing)
         n_sess = int(round(n_point * max(1.0, frac)))
-        sim, _ = _point(splits, placed, payloads, rate=rate,
+        sim, _ = _point(splits, params, payloads, rate=rate,
                         n_sessions=n_sess, n_replicas=n_curve,
                         seed=seed + 1)
         st = _ttfp_stats(sim)
@@ -288,7 +288,7 @@ def run(quick=True, *, smoke=False, seed=0):
     # ---- shed-vs-queue A/B at 2x knee -------------------------------
     over_rate = 2.0 * knee_rate
     n_over = 2 * n_point       # sustained overload: see the curve note
-    sim_q, _ = _point(splits, placed, payloads, rate=over_rate,
+    sim_q, _ = _point(splits, params, payloads, rate=over_rate,
                       n_sessions=n_over, n_replicas=n_curve,
                       seed=seed + 2)
     q_stats = _ttfp_stats(sim_q)
@@ -297,7 +297,7 @@ def run(quick=True, *, smoke=False, seed=0):
     ctrl = lambda: AdmissionController(  # noqa: E731 - rebuilt per pass
         AdmissionPolicy(deadline_s=deadline, enter_frac=1.0, exit_frac=0.5),
         n_curve)
-    sim_s, sess_s = _point(splits, placed, payloads, rate=over_rate,
+    sim_s, sess_s = _point(splits, params, payloads, rate=over_rate,
                            n_sessions=n_over, n_replicas=n_curve,
                            admission=ctrl, seed=seed + 2,
                            profile=_profile())
@@ -313,7 +313,7 @@ def run(quick=True, *, smoke=False, seed=0):
     passed_knee = bool(shed_ok and queue_blows)
 
     # ---- parity spot-check (atol 0) ---------------------------------
-    parity_n = _parity_check(sim_s, sess_s, splits, placed, payloads)
+    parity_n = _parity_check(sim_s, sess_s, splits, params, payloads)
 
     # ---- weak scaling: offered ~ replicas, constant total sessions --
     replica_counts = (1, 2, 4) if smoke else (1, 2, 4, 8)
@@ -325,7 +325,7 @@ def run(quick=True, *, smoke=False, seed=0):
         # (light-load) per-replica capacity so every config is stable
         # and the read is arrival-limited throughput
         rate = scale_frac * cap_light * r
-        sim, _ = _point(splits, placed, payloads, rate=rate,
+        sim, _ = _point(splits, params, payloads, rate=rate,
                         n_sessions=2 * n_point,  # constant total work
                         n_replicas=r, seed=seed + 3)
         rep = sim.report()

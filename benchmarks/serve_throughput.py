@@ -105,7 +105,8 @@ def _ragged_section(n_sessions, n_ticks, seed=1):
 
     cfg = C.emsnet_cfg(True, text_encoder="microbert", vocab_size=512,
                        max_text_len=16, vitals_hidden=32,
-                       use_flash_text=True, flash_segments=True)
+                       use_flash_text=True, flash_segments=True,
+                       flash_block=8)
     zoo = emsnet_zoo(cfg)
     splits = {k: split(m) for k, m in zoo.items()}
     shared = zoo["text+vitals+scene"].init_fn(jax.random.PRNGKey(0))

@@ -314,16 +314,15 @@ def run_fleet(args, devs):
     kw = dict(bucketer=Bucketer(max_buckets={"vitals": cfg.vitals_len,
                                              "text": cfg.max_text_len}),
               batch_bucket_min=8)
-    sim4 = RegionSim(splits, params, n_replicas=4, devices=devs[:4],
-                     engine_kw=dict(kw))
+    sim4 = RegionSim(splits, params, n_replicas=4, engine_kw=dict(kw))
     rep4 = sim4.run(sessions, pay)
-    sim1 = RegionSim(splits, params, n_replicas=1, devices=devs[:1],
-                     engine_kw=dict(kw))
+    sim1 = RegionSim(splits, params, n_replicas=1, engine_kw=dict(kw))
     rep1 = sim1.run(sessions, pay)
     served = [pr["sessions"] for pr in rep4["per_replica"]]
     if min(served) == 0:
         fail("fleet", f"a replica served no session: {served}")
     worst = 0.0
+    used = set()
     for s in sessions:
         a, b = sim4.final_outputs(s.sid), sim1.final_outputs(s.sid)
         if a is None or b is None:
@@ -333,14 +332,17 @@ def run_fleet(args, devs):
             if a[k].devices() != {devs[r]}:
                 fail("fleet", f"{s.sid} on replica {r}: output on "
                               f"{a[k].devices()}, not {devs[r]}")
+            used |= a[k].devices()
             worst = max(worst, float(np.abs(np.asarray(a[k])
                                             - np.asarray(b[k])).max()))
+    if len(used) != 4:
+        fail("fleet", f"the finals sit on {len(used)} devices, not 4")
     if not worst <= LOGIT_ATOL:
         fail("fleet", f"4- vs 1-replica finals: max |d| {worst:.6g} > "
                       f"{LOGIT_ATOL}")
     phase("fleet", f"{len(sessions)} sessions, all final on both; 4 "
-                   f"replicas on {len({devs[r] for r in range(4)})} "
-                   f"distinct devices serving {served} sessions; finals "
+                   f"replicas' finals on {len(used)} distinct devices, "
+                   f"serving {served} sessions; finals "
                    f"vs 1 replica max|d| {worst:.3e} (atol {LOGIT_ATOL}); "
                    f"flushes 4x {sum(p['flushes'] for p in rep4['per_replica'])}"
                    f" / 1x {rep1['per_replica'][0]['flushes']}")

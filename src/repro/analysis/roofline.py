@@ -19,11 +19,10 @@ from dataclasses import dataclass, field
 
 # Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 # TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
-# 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
-# interconnect over 4 links (50 GB/s per link).
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over 4
+# links (50 GB/s per link).
 PEAKS = {
-    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
-                    "hbm_bw": 819e9, "link_bw": 50e9},
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
 }
 
 

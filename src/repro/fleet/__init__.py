@@ -11,11 +11,9 @@ is a fleet:
     lags carried as explicit per-event arrival sequences through
     ``core.episodes.async_episode(times=...)``.
   * ``region`` — ``RegionSim``: N ``EMSServeEngine`` replicas built
-    from ONE ``build_engine`` spec, each replica's parameters on a
-    device of its own (or one pytree placed across a jax device mesh
-    by the ``distributed.sharding`` policy), a consistent-hash +
-    least-loaded session router, and a
-    shared simulated clock (flush cost = measured wall seconds of the
+    from ONE ``build_engine`` spec, replica r's parameters on device
+    ``r % n_devices``, a consistent-hash + least-loaded session router,
+    and a shared simulated clock (flush cost = measured wall seconds of the
     real XLA calls, flush start gated on data availability).
   * ``admission`` — deadline/queue-depth admission control with
     hysteresis; overload sheds NEW sessions to the on-glass provisional
@@ -29,8 +27,7 @@ sessions/s scaling vs replica count, shed-vs-queue A/B) ->
 """
 from .admission import AdmissionController, AdmissionPolicy, AdmitAll  # noqa: F401
 from .region import (ConsistentHashRouter, DegradedRecord,  # noqa: F401
-                     GlassShedPath, RegionSim, fleet_mesh,
-                     place_fleet_params, place_replica_params)
+                     GlassShedPath, RegionSim, place_replica_params)
 from .workload import (IncidentSession, diurnal_rate,  # noqa: F401
                        diurnal_times, generate_workload, merge_sessions,
                        poisson_times)

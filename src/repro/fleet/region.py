@@ -3,14 +3,11 @@
 The fleet story in one file:
 
   * **One spec, N replicas** — ``RegionSim`` builds every replica with
-    the same ``build_engine`` spec string. With ``devices`` each replica
-    owns one device: its parameters are placed there
-    (``place_replica_params``) and its flushes run there. Without it
-    every replica shares the pytree it is given, for example one that
-    ``fleet_mesh``/``place_fleet_params`` replicated over a
-    ``('data','model')`` mesh through the ``distributed/sharding.py``
-    policy. Pytree identity is preserved either way, so the
-    ``share_encoders`` grouped-tail fast path keeps working.
+    the same ``build_engine`` spec string. Replica ``r`` owns device
+    ``jax.devices()[r % n_devices]``: its parameters are placed there
+    (``place_replica_params``) and its flushes run there. Pytree
+    identity is preserved within a replica, so the ``share_encoders``
+    grouped-tail fast path keeps working.
 
   * **Shared simulated clock** — arrivals are replayed in global fleet
     time; each replica carries a serving clock that can never run ahead
@@ -47,80 +44,24 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from ..core.splitter import select_model
-from ..distributed.sharding import Policy
 from ..obs import Metrics
 from ..serving.api import build_engine
 from .admission import AdmitAll
 from .workload import IncidentSession, merge_sessions
 
-__all__ = ["fleet_mesh", "place_fleet_params", "place_replica_params",
-           "ConsistentHashRouter",
+__all__ = ["place_replica_params", "ConsistentHashRouter",
            "DegradedRecord", "GlassShedPath", "RegionSim"]
 
 
-# ------------------------------------------------------------------ mesh
-
-def fleet_mesh(n_replicas: Optional[int] = None):
-    """A ('data', 'model') mesh over the available jax devices (at most
-    ``n_replicas`` of them; 'model' is size 1 — no tensor parallelism
-    in the EMSNet zoo). Under host-device emulation
-    (``XLA_FLAGS=--xla_force_host_platform_device_count=N``) this is
-    the N-way fleet mesh; on a single real device it degrades to 1."""
-    from jax.sharding import Mesh
-    devs = jax.devices()
-    n = len(devs) if n_replicas is None else max(1, min(n_replicas,
-                                                        len(devs)))
-    return Mesh(np.asarray(devs[:n]).reshape(n, 1), ("data", "model"))
-
-
-def place_fleet_params(params: Dict[str, dict], mesh, *, cfg=None,
-                       strategy: str = "2d"):
-    """Place the engine parameter pytrees onto ``mesh`` through the
-    ``distributed.sharding.Policy`` PartitionSpecs.
-
-    ``params`` maps model name -> pytree; names sharing ONE pytree (the
-    subset zoo) are placed once and keep identity, so the engine's
-    ``share_encoders`` grouped-tail identity check still holds.
-    Returns ``(placed_params, report)`` where the report counts
-    replicated vs sharded leaves and total bytes."""
-    pol = Policy(cfg, mesh, strategy=strategy)
-    placed_by_id: Dict[int, dict] = {}
-    leaves_total = sharded = 0
-    nbytes = 0
-    for p in params.values():
-        if id(p) in placed_by_id:
-            continue
-        pspecs = pol.param_pspecs(p)
-        for spec in jax.tree.leaves(
-                pspecs, is_leaf=lambda x: isinstance(x, P)):
-            leaves_total += 1
-            if any(a is not None for a in tuple(spec)):
-                sharded += 1
-        nbytes += sum(x.size * x.dtype.itemsize
-                      for x in jax.tree.leaves(p))
-        placed_by_id[id(p)] = jax.device_put(p, pol.shardings(pspecs))
-    report = {
-        "devices": len(mesh.devices.reshape(-1)),
-        "axis_sizes": {k: int(v)
-                       for k, v in zip(mesh.axis_names,
-                                       mesh.devices.shape)},
-        "strategy": strategy,
-        "param_leaves": leaves_total,
-        "sharded_leaves": sharded,
-        "replicated_leaves": leaves_total - sharded,
-        "param_bytes": int(nbytes),
-    }
-    return {k: placed_by_id[id(v)] for k, v in params.items()}, report
-
+# ------------------------------------------------------------- placement
 
 def place_replica_params(params: Dict[str, dict], device):
     """Commit the engine parameter pytrees to one ``device``. Names
     sharing ONE pytree (the subset zoo) are placed once and keep
-    identity, as in ``place_fleet_params``."""
+    identity, so the engine's ``share_encoders`` grouped-tail identity
+    check still holds."""
     placed_by_id: Dict[int, dict] = {}
     for p in params.values():
         if id(p) not in placed_by_id:
@@ -283,20 +224,16 @@ class RegionSim:
     sessions go to the ``GlassShedPath``. Nothing is ever dropped:
     ``sessions_offered == admitted + shed`` is an invariant.
 
-    ``devices`` (one per replica) gives replica ``r`` its own copy of
-    ``params`` on ``devices[r]``, so its flushes run on that device."""
+    Replica ``r`` holds its own copy of ``params`` on
+    ``jax.devices()[r % n_devices]``, so its flushes run there."""
 
     def __init__(self, models, params, *, n_replicas: int = 2,
                  spec: str = "batch+stream", admission=None,
                  profile=None, router: Optional[ConsistentHashRouter] = None,
                  tracer=None, svc_prior_s: float = 0.002,
-                 engine_kw: Optional[dict] = None,
-                 devices: Optional[Sequence] = None):
+                 engine_kw: Optional[dict] = None):
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
-        if devices is not None and len(devices) != n_replicas:
-            raise ValueError(f"{len(devices)} devices for "
-                             f"{n_replicas} replicas")
         self.n_replicas = n_replicas
         self.admission = admission if admission is not None else AdmitAll()
         self.router = (router if router is not None
@@ -309,11 +246,12 @@ class RegionSim:
         self.tracer = tracer
         kw = dict(share_encoders=True, deadline_s=None)
         kw.update(engine_kw or {})
-        replica_params = ([place_replica_params(params, d)
-                           for d in devices] if devices is not None
-                          else [params] * n_replicas)
-        self.replicas = [build_engine(models, rp, spec, tracer=tracer, **kw)
-                         for rp in replica_params]
+        devs = jax.devices()
+        self.devices = [devs[r % len(devs)] for r in range(n_replicas)]
+        self.replicas = [build_engine(models,
+                                      place_replica_params(params, d),
+                                      spec, tracer=tracer, **kw)
+                         for d in self.devices]
         self.glass = (GlassShedPath(models, params, profile,
                                     bucketer=kw.get("bucketer"),
                                     metrics=self.metrics, tracer=tracer)

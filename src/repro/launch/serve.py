@@ -376,8 +376,6 @@ def serve_fleet(args):
 
     cfg = emsnet_config(text_encoder=args.text_encoder, vocab_size=2048)
     splits, params = build_zoo(cfg)
-    devs = jax.devices()
-    devices = [devs[r % len(devs)] for r in range(args.replicas)]
     payloads = sample_payloads(cfg)
     payload_fn = lambda sid, ev: payloads[ev.modality]  # noqa: E731
 
@@ -394,10 +392,10 @@ def serve_fleet(args):
                               args.replicas)
     sim = RegionSim(splits, params, n_replicas=args.replicas,
                     admission=ctrl, profile=ProfileTable(base=base),
-                    tracer=tracer, devices=devices)
+                    tracer=tracer)
     nbytes = sum(x.nbytes for x in jax.tree.leaves(
         params["text+vitals+scene"]))
-    print(f"fleet: {args.replicas} replicas over {len(set(devices))} "
+    print(f"fleet: {args.replicas} replicas over {len(set(sim.devices))} "
           f"device(s), {nbytes / 1e6:.1f} MB of params per replica, "
           f"admission deadline {deadline * 1e3:.0f} ms")
 

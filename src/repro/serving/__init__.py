@@ -87,7 +87,7 @@ no emit before its inputs) from the file alone.
 
 Fleet scale lives one package up (``repro.fleet``): ``RegionSim``
 replays seeded open-loop Poisson/diurnal incident arrivals against N
-replicas of ONE ``build_engine`` spec over mesh-placed params, with
+replicas of ONE ``build_engine`` spec, each on a device of its own, with
 consistent-hash routing and deadline-hysteresis admission control that
 sheds overload to on-glass ``degraded``-tagged partials (launcher:
 ``--fleet RATE --replicas N``; benchmark: ``benchmarks/fleet_load.py``).

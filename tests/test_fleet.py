@@ -15,8 +15,9 @@ Four claims under test:
     ``enter_frac * deadline``, keeps shedding until strictly below
     ``exit_frac * deadline`` (burst recovery), honors the queue cap,
     and accounts for every decision;
-  * **fleet scale never buys drift or loss** — a ``RegionSim`` run over
-    mesh-placed params conserves sessions (offered == admitted + shed),
+  * **fleet scale never buys drift or loss** — a ``RegionSim`` run, each
+    replica on a device of its own, conserves sessions
+    (offered == admitted + shed),
     finalizes every admitted session at bit-parity (atol 0) with a
     per-event reference engine built with the same batch bucket, and
     shed sessions emit ONLY ``degraded``-tagged partials — counted,
@@ -30,8 +31,8 @@ import pytest
 from repro.core import ProfileTable, emsnet_zoo, split
 from repro.fleet import (AdmissionController, AdmissionPolicy, AdmitAll,
                          ConsistentHashRouter, RegionSim, diurnal_rate,
-                         diurnal_times, fleet_mesh, generate_workload,
-                         merge_sessions, place_fleet_params, poisson_times)
+                         diurnal_times, generate_workload, merge_sessions,
+                         place_replica_params, poisson_times)
 from repro.obs import StreamingTracer, audit_file
 from repro.serving.api import build_engine
 
@@ -55,7 +56,6 @@ def fleet_models(tiny_emsnet_cfg):
     splits = {k: split(m) for k, m in zoo.items()}
     shared = zoo["text+vitals+scene"].init_fn(jax.random.PRNGKey(0))
     params = {k: shared for k in zoo}
-    placed, report = place_fleet_params(params, fleet_mesh())
     rng = np.random.default_rng(0)
     payloads = {
         "text": jnp.asarray(rng.integers(1, cfg.vocab_size, (1, 11)),
@@ -65,7 +65,7 @@ def fleet_models(tiny_emsnet_cfg):
         "scene": jnp.asarray(rng.integers(0, 2, (1, cfg.scene_dim)),
                              jnp.float32),
     }
-    return cfg, splits, shared, placed, report, payloads
+    return cfg, splits, shared, params, payloads
 
 
 def _flatten(sessions):
@@ -231,32 +231,29 @@ def test_admit_all_never_sheds():
 
 # ---------------------------------------------------------------- placement
 
-def test_place_fleet_params_identity_and_report(fleet_models):
-    _, _, shared, placed, report, _ = fleet_models
-    # one shared pytree in -> one placed pytree out, identity preserved
+def test_place_replica_params_identity(fleet_models):
+    _, _, shared, params, _ = fleet_models
+    dev = jax.devices()[-1]
+    placed = place_replica_params(params, dev)
+    assert set(placed) == set(params)
+    # one shared pytree in -> one committed pytree out, identity kept
     # across zoo keys (the share_encoders grouped-tail check needs it)
     assert len({id(v) for v in placed.values()}) == 1
     ref = jax.tree.leaves(shared)
     got = jax.tree.leaves(next(iter(placed.values())))
     assert len(ref) == len(got)
     for a, b in zip(ref, got):
+        assert b.committed and b.devices() == {dev}
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert report["devices"] >= 1
-    assert report["axis_sizes"]["model"] == 1
-    assert report["param_leaves"] == (report["sharded_leaves"]
-                                      + report["replicated_leaves"])
-    assert report["param_leaves"] == len(ref)
-    assert report["param_bytes"] == sum(
-        x.size * x.dtype.itemsize for x in ref)
 
 
 # --------------------------------------------------------------- region sim
 
 def test_region_sim_conservation_and_bit_parity(fleet_models):
-    _, splits, _, placed, _, payloads = fleet_models
+    _, splits, _, params, payloads = fleet_models
     sessions = generate_workload(3.0, 2.0, seed=0, time_scale=0.2)
     assert len(sessions) >= 2
-    sim = RegionSim(splits, placed, n_replicas=2,
+    sim = RegionSim(splits, params, n_replicas=2,
                     engine_kw=dict(ENGINE_KW))
     rep = sim.run(sessions, lambda sid, ev: payloads[ev.modality])
 
@@ -272,7 +269,7 @@ def test_region_sim_conservation_and_bit_parity(fleet_models):
     # every admitted session's finals match a per-event reference engine
     # built with the SAME fixed batch bucket, at atol 0
     for s in sessions:
-        ref = build_engine(splits, placed, "batch+stream",
+        ref = build_engine(splits, params, "batch+stream",
                            share_encoders=True, deadline_s=None,
                            **ENGINE_KW)
         preds = []
@@ -299,29 +296,28 @@ def test_region_sim_conservation_and_bit_parity(fleet_models):
 
 
 def test_region_sim_replica_devices(fleet_models):
-    """``devices`` gives every replica its own committed copy of the
-    params (one pytree per replica, shared across its zoo keys), and the
-    finals equal those of replicas that share one pytree."""
-    _, splits, shared, placed, _, payloads = fleet_models
+    """Replica ``r`` holds its own committed copy of the params on
+    ``jax.devices()[r % n_devices]`` (one pytree per replica, shared
+    across its zoo keys), and 3 replicas give the finals of 1."""
+    _, splits, _, params, payloads = fleet_models
     sessions = generate_workload(3.0, 2.0, seed=0, time_scale=0.2)
-    dev = jax.devices()[0]
-    with pytest.raises(ValueError, match="devices for"):
-        RegionSim(splits, placed, n_replicas=2, devices=[dev])
-    sim = RegionSim(splits, {k: shared for k in placed}, n_replicas=2,
-                    engine_kw=dict(ENGINE_KW), devices=[dev, dev])
+    devs = jax.devices()
+    sim = RegionSim(splits, params, n_replicas=3,
+                    engine_kw=dict(ENGINE_KW))
+    assert sim.devices == [devs[r % len(devs)] for r in range(3)]
     per_replica = [e.params for e in sim.replicas]
-    for p in per_replica:
+    for p, dev in zip(per_replica, sim.devices):
         assert len({id(v) for v in p.values()}) == 1
-        leaf = jax.tree.leaves(next(iter(p.values())))[0]
-        assert leaf.committed and leaf.devices() == {dev}
-    assert per_replica[0]["text"] is not per_replica[1]["text"]
-    base = RegionSim(splits, placed, n_replicas=2,
-                     engine_kw=dict(ENGINE_KW))
+        for leaf in jax.tree.leaves(next(iter(p.values()))):
+            assert leaf.committed and leaf.devices() == {dev}
+    assert len({id(p["text"]) for p in per_replica}) == 3
+    one = RegionSim(splits, params, n_replicas=1,
+                    engine_kw=dict(ENGINE_KW))
     payload_fn = lambda sid, ev: payloads[ev.modality]  # noqa: E731
     sim.run(sessions, payload_fn)
-    base.run(sessions, payload_fn)
+    one.run(sessions, payload_fn)
     for s in sessions:
-        got, want = sim.final_outputs(s.sid), base.final_outputs(s.sid)
+        got, want = sim.final_outputs(s.sid), one.final_outputs(s.sid)
         assert got is not None and set(got) == set(want)
         for k in want:
             np.testing.assert_array_equal(np.asarray(got[k]),
@@ -329,14 +325,14 @@ def test_region_sim_replica_devices(fleet_models):
 
 
 def test_region_sim_shed_sessions_degrade_only(fleet_models, tmp_path):
-    _, splits, _, placed, _, payloads = fleet_models
+    _, splits, _, params, payloads = fleet_models
     sessions = generate_workload(3.0, 2.0, seed=1, time_scale=0.2)
     # deadline far below the svc prior: every session sheds to glass
     ctrl = AdmissionController(
         AdmissionPolicy(deadline_s=1e-4, enter_frac=1.0, exit_frac=0.5), 2)
     path = tmp_path / "fleet.jsonl"
     tracer = StreamingTracer(path, buffer=16)
-    sim = RegionSim(splits, placed, n_replicas=2, admission=ctrl,
+    sim = RegionSim(splits, params, n_replicas=2, admission=ctrl,
                     profile=GLASS_PROFILE, tracer=tracer,
                     engine_kw=dict(ENGINE_KW))
     rep = sim.run(sessions, lambda sid, ev: payloads[ev.modality])
