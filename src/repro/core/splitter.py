@@ -85,11 +85,26 @@ def select_model(models: Dict[str, SplitModel], observed) -> str | None:
     return best
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: what jit calls the program it lowers
+    (``jit_<name>``), so compile logs and the device trace's module
+    line tell the pieces apart."""
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def split(module: MultimodalModule, *, jit: bool = True) -> SplitModel:
+    """Each piece as its own program, named ``encode_<modality>``,
+    ``tail_<subset>`` and ``full_<subset>`` (the subset's modalities
+    joined by ``_``)."""
     wrap = jax.jit if jit else (lambda f: f)
-    encoders = {m: wrap(fn) for m, fn in module.encoder_fns.items()}
-    tail = wrap(module.tail_fn)
-    full = wrap(module.full_fn())
+    subset = "_".join(module.modalities)
+    encoders = {m: wrap(_named(fn, f"encode_{m}"))
+                for m, fn in module.encoder_fns.items()}
+    tail = wrap(_named(module.tail_fn, f"tail_{subset}"))
+    full = wrap(_named(module.full_fn(), f"full_{subset}"))
     return SplitModel(module=module, encoders=encoders, tail=tail, full=full)
 
 

@@ -24,9 +24,15 @@ loadable in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``:
 naming each track.  Track ids are assigned from the sorted set of
 track names so they never depend on event arrival order.
 
+``Tracer.scope`` times a block of host work as one span, and while the
+block runs also holds a ``jax.profiler.TraceAnnotation`` of the same
+name: a JAX profile of the process (TensorBoard, Perfetto) then shows
+the program's phases on its host plane, on the device ops' clock.
+
 ``Tracer.disabled`` is a shared no-op singleton that is falsy, so hot
 paths guard instrumentation with ``if self.tracer:`` and pay one
-branch when tracing is off.
+branch when tracing is off; its ``scope`` returns one shared no-op
+context.
 """
 from __future__ import annotations
 
@@ -97,6 +103,15 @@ class Tracer:
         self.events.append(TraceEvent(name, cat, ts, None, track,
                                       args, self._seq))
 
+    def scope(self, name: str, cat: str, *, at: Optional[float] = None,
+              track: str = "engine", **args) -> "_Scope":
+        """Context manager recording one span ``[entry, exit]`` of the
+        tracer clock, with ``args`` (``set(**more)`` adds to them from
+        inside the block). ``at`` starts the span at a time already read
+        instead of on entry. A profiler annotation named ``name`` is open
+        around the block, so a JAX profile shows it on the host plane."""
+        return _Scope(self, name, cat, at, track, args)
+
     def clear(self) -> None:
         self.events.clear()
         self._seq = 0
@@ -142,6 +157,62 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f, sort_keys=True, separators=(",", ":"))
         return len(self.events)
+
+
+class _Scope:
+    """One open ``Tracer.scope``; records its span on exit."""
+
+    __slots__ = ("tracer", "name", "cat", "t0", "track", "args", "_ann")
+
+    def __init__(self, tracer, name, cat, t0, track, args):
+        self.tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.t0 = t0
+        self.track = track
+        self.args = args
+        self._ann = None
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def __enter__(self) -> "_Scope":
+        try:    # imported here: obs stays importable without jax
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            pass
+        else:
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        if self.t0 is None:
+            self.t0 = self.tracer.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = self.tracer.now()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.tracer.span(self.name, self.cat, self.t0, t1,
+                         track=self.track, **self.args)
+
+
+class _NullScope:
+    """The disabled tracer's scope: enters, sets and exits doing
+    nothing."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_NullScope":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SCOPE = _NullScope()
 
 
 class StreamingTracer(Tracer):
@@ -259,6 +330,9 @@ class _DisabledTracer(Tracer):
 
     def instant(self, *a, **kw) -> None:
         pass
+
+    def scope(self, *a, **kw) -> _NullScope:
+        return _NULL_SCOPE
 
 
 Tracer.disabled = _DisabledTracer()

@@ -858,95 +858,116 @@ class EMSServeEngine:
         return w
 
     def _run_encoder_chunk(self, m, sids, batch, upos, total_pos,
-                           sync_targets):
+                           sync_targets, flush_id):
         """Run every consuming model's encoder over one prepared batch
         (stacked or packed), scatter rows into the feature cache, and
         account the padding tax. Returns (n_calls, useful, padded)."""
         runners = (self._consumers(m)[:1] if self.share_encoders
                    else self._consumers(m))
-        n, useful, padded = 0, 0.0, 0.0
-        for name, sm in runners:
-            feats = sm.encoders[m](self.params[name], batch)
-            n += 1
-            w = self._weight(name, m)
-            useful += w * upos
-            padded += w * (total_pos - upos)
-            sync_targets.append(feats)
-            for i, sid in enumerate(sids):
-                st = self.sessions[sid]
-                self.cache.put(self._cache_key(sid, name), m,
-                               feats[i:i + 1], step=st.step, tier="glass")
-        return n, useful, padded
+        useful, padded, outs = 0.0, 0.0, []
+        with self.tracer.scope("flush.encode", "flush", flush_id=flush_id,
+                               calls=len(runners)):
+            for name, sm in runners:
+                feats = sm.encoders[m](self.params[name], batch)
+                outs.append((name, feats))
+                w = self._weight(name, m)
+                useful += w * upos
+                padded += w * (total_pos - upos)
+                sync_targets.append(feats)
+        with self.tracer.scope("flush.scatter", "flush", flush_id=flush_id,
+                               calls=len(runners) * len(sids)):
+            for name, feats in outs:
+                for i, sid in enumerate(sids):
+                    st = self.sessions[sid]
+                    self.cache.put(self._cache_key(sid, name), m,
+                                   feats[i:i + 1], step=st.step,
+                                   tier="glass")
+        return len(runners), useful, padded
 
-    def _flush_encode(self, touched, sync_targets):
+    def _flush_encode(self, touched, sync_targets, flush_id):
         """Bucketed encode: one stacked call per (modality, bucket[,
         chunk]) per consuming model."""
+        with self.tracer.scope("flush.prep", "flush",
+                               flush_id=flush_id) as phase:
+            groups = self._encode_groups(touched)
+            # one Bucketer.fit per grouped input
+            phase.set(calls=sum(map(len, groups.values()))
+                      if self.bucketer else 0)
         n_enc, useful, padded = 0, 0.0, 0.0
-        for (m, _shape), items in self._encode_groups(touched).items():
+        for (m, _shape), items in groups.items():
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
-                stacked = stack_bucketed([p for _, p, _ in chunk],
-                                         self._bucket_rows(len(chunk)))
-                lead = stacked["x"] if isinstance(stacked, dict) else stacked
-                plen = lead.shape[1] if lead.ndim >= 2 else 1
-                upos = sum(min(nat, plen) for _, _, nat in chunk)
+                with self.tracer.scope("flush.prep", "flush",
+                                       flush_id=flush_id, calls=1):
+                    stacked = stack_bucketed([p for _, p, _ in chunk],
+                                             self._bucket_rows(len(chunk)))
+                    lead = (stacked["x"] if isinstance(stacked, dict)
+                            else stacked)
+                    plen = lead.shape[1] if lead.ndim >= 2 else 1
+                    upos = sum(min(nat, plen) for _, _, nat in chunk)
                 c, u, pd = self._run_encoder_chunk(
                     m, [sid for sid, _, _ in chunk], stacked, upos,
-                    lead.shape[0] * plen, sync_targets)
+                    lead.shape[0] * plen, sync_targets, flush_id)
                 n_enc += c
                 useful += u
                 padded += pd
         return n_enc, useful, padded
 
-    def _flush_encode_ragged(self, touched, sync_targets):
+    def _flush_encode_ragged(self, touched, sync_targets, flush_id):
         """Ragged encode: ONE packed call per variable-length modality
         (per chunk, per consuming model) regardless of how many length
         buckets are live; fixed-size modalities keep the stacked path."""
         n_enc, useful, padded = 0, 0.0, 0.0
         ragged_mods = defaultdict(list)      # m -> [(sid, raw, nat)]
         fixed = defaultdict(list)            # (m, shape) -> [(sid, raw, nat)]
-        for sid in touched:
-            st = self.sessions[sid]
-            for m in sorted(st.dirty):
-                if not self._consumers(m):
-                    continue
-                x = st.inputs[m]
-                if m in ("text", "vitals"):
-                    ragged_mods[m].append((st.sid, x, self._nat_len(x)))
-                else:
-                    fixed[(m, tuple(x.shape))].append(
-                        (st.sid, x, self._nat_len(x)))
+        with self.tracer.scope("flush.prep", "flush", flush_id=flush_id,
+                               calls=0):
+            for sid in touched:
+                st = self.sessions[sid]
+                for m in sorted(st.dirty):
+                    if not self._consumers(m):
+                        continue
+                    x = st.inputs[m]
+                    if m in ("text", "vitals"):
+                        ragged_mods[m].append((st.sid, x, self._nat_len(x)))
+                    else:
+                        fixed[(m, tuple(x.shape))].append(
+                            (st.sid, x, self._nat_len(x)))
         for m, items in sorted(ragged_mods.items()):
             cap = self.ragged.max_lengths.get(m)
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
-                packed = self.ragged.pack(m, [x for _, x, _ in chunk])
-                total = (packed["tokens"] if m == "text"
-                         else packed["x"]).shape[1]
-                upos = sum(nat if cap is None else min(nat, cap)
-                           for _, _, nat in chunk)
+                with self.tracer.scope("flush.prep", "flush",
+                                       flush_id=flush_id, calls=1):
+                    packed = self.ragged.pack(m, [x for _, x, _ in chunk])
+                    total = (packed["tokens"] if m == "text"
+                             else packed["x"]).shape[1]
+                    upos = sum(nat if cap is None else min(nat, cap)
+                               for _, _, nat in chunk)
                 c, u, pd = self._run_encoder_chunk(
                     m, [sid for sid, _, _ in chunk], packed, upos, total,
-                    sync_targets)
+                    sync_targets, flush_id)
                 n_enc += c
                 useful += u
                 padded += pd
         for (m, _shape), items in sorted(fixed.items()):
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
-                stacked = stack_bucketed([x for _, x, _ in chunk],
-                                         self._bucket_rows(len(chunk)))
-                rows = (stacked["x"] if isinstance(stacked, dict)
-                        else stacked).shape[0]
+                with self.tracer.scope("flush.prep", "flush",
+                                       flush_id=flush_id, calls=1):
+                    stacked = stack_bucketed([x for _, x, _ in chunk],
+                                             self._bucket_rows(len(chunk)))
+                    rows = (stacked["x"] if isinstance(stacked, dict)
+                            else stacked).shape[0]
                 c, u, pd = self._run_encoder_chunk(
                     m, [sid for sid, _, _ in chunk], stacked, len(chunk),
-                    rows, sync_targets)
+                    rows, sync_targets, flush_id)
                 n_enc += c
                 useful += u
                 padded += pd
         return n_enc, useful, padded
 
-    def _flush_tails(self, tail_groups, sync_targets):
+    def _flush_tails(self, tail_groups, sync_targets, flush_id):
         """One batched tail call per selected model (per chunk)."""
         n_tail, useful, padded = 0, 0.0, 0.0
         emitted = []      # (sid, name, modalities, outputs, step)
@@ -956,24 +977,41 @@ class EMSServeEngine:
             w = self._weight(name, "heads")
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
-                sids = [sid for sid, _ in chunk]
-                stacked = {mm: stack_bucketed([f[mm] for _, f in chunk],
-                                              self._bucket_rows(len(chunk)))
-                           for mm in mods}
-                outs = sm.tail(self.params[name], stacked)
-                n_tail += 1
-                rows = next(iter(stacked.values())).shape[0]
-                useful += w * len(chunk)
-                padded += w * (rows - len(chunk))
-                sync_targets.append(outs)
-                for i, sid in enumerate(sids):
-                    st = self.sessions[sid]
-                    row = jax.tree.map(lambda a: a[i:i + 1], outs)
-                    emitted.append((sid, name, tuple(mods), row, st.step))
-                    for mm in mods:   # the result carries the cache back
-                        self.cache.touch(self._cache_key(sid, name), mm,
-                                         st.step)
+                with self.tracer.scope("flush.prep", "flush",
+                                       flush_id=flush_id, calls=len(mods)):
+                    stacked = {mm: stack_bucketed(
+                                   [f[mm] for _, f in chunk],
+                                   self._bucket_rows(len(chunk)))
+                               for mm in mods}
+                with self.tracer.scope("flush.tail", "flush",
+                                       flush_id=flush_id, calls=1):
+                    outs = sm.tail(self.params[name], stacked)
+                    n_tail += 1
+                    rows = next(iter(stacked.values())).shape[0]
+                    useful += w * len(chunk)
+                    padded += w * (rows - len(chunk))
+                    sync_targets.append(outs)
+                emitted += self._scatter_tail_rows(
+                    [(sid, name) for sid, _ in chunk], outs, flush_id)
         return n_tail, emitted, useful, padded
+
+    def _scatter_tail_rows(self, chunk, outs, flush_id):
+        """Slice one tail call's outputs into per-session rows and
+        re-stamp the cache entries each row consumed. ``chunk`` lists
+        (sid, model name) per row; returns the emitted entries."""
+        emitted = []
+        n_leaves = len(jax.tree_util.tree_leaves(outs))
+        with self.tracer.scope("flush.scatter", "flush", flush_id=flush_id,
+                               calls=len(chunk) * n_leaves):
+            for i, (sid, name) in enumerate(chunk):
+                st = self.sessions[sid]
+                row = jax.tree.map(lambda a: a[i:i + 1], outs)
+                mods = self.models[name].modalities()
+                emitted.append((sid, name, tuple(mods), row, st.step))
+                for mm in mods:   # the result carries the cache back
+                    self.cache.touch(self._cache_key(sid, name), mm,
+                                     st.step)
+        return emitted
 
     def _grouped_tail_target(self, tail_groups) -> Optional[str]:
         """The ONE grouped tail is legal when a full-fusion model exists,
@@ -996,7 +1034,8 @@ class EMSServeEngine:
             return None
         return full_name
 
-    def _flush_tails_grouped(self, tail_groups, full_name, sync_targets):
+    def _flush_tails_grouped(self, tail_groups, full_name, sync_targets,
+                             flush_id):
         """ONE stacked tail call for every pending (session, subset) —
         flush then issues O(modalities) + 1 kernels instead of
         O(modalities x buckets) + O(subsets). Each row is the full-width
@@ -1016,25 +1055,25 @@ class EMSServeEngine:
         for c0 in range(0, len(rows), self.max_coalesce):
             chunk = rows[c0:c0 + self.max_coalesce]
             nb = self._bucket_rows(len(chunk))
-            stacked = {
-                m: stack_bucketed(
-                    [f.get(m, jnp.zeros((1, dims[m]), jnp.float32))
-                     for _, _, f in chunk], nb)
-                for m in full_mods}
-            outs = full_sm.tail(self.params[full_name], stacked)
-            n_tail += 1
-            sync_targets.append(outs)
-            subw = sum(sum(dims[m] for m in self.models[name].modalities())
-                       for _, name, _ in chunk) / fullw
-            useful += w * subw
-            padded += w * (nb - subw)
-            for i, (sid, name, _f) in enumerate(chunk):
-                st = self.sessions[sid]
-                row = jax.tree.map(lambda a: a[i:i + 1], outs)
-                mods = self.models[name].modalities()
-                emitted.append((sid, name, tuple(mods), row, st.step))
-                for mm in mods:
-                    self.cache.touch(self._cache_key(sid, name), mm, st.step)
+            with self.tracer.scope("flush.prep", "flush", flush_id=flush_id,
+                                   calls=len(full_mods)):
+                stacked = {
+                    m: stack_bucketed(
+                        [f.get(m, jnp.zeros((1, dims[m]), jnp.float32))
+                         for _, _, f in chunk], nb)
+                    for m in full_mods}
+            with self.tracer.scope("flush.tail", "flush", flush_id=flush_id,
+                                   calls=1):
+                outs = full_sm.tail(self.params[full_name], stacked)
+                n_tail += 1
+                sync_targets.append(outs)
+                subw = sum(sum(dims[m]
+                               for m in self.models[name].modalities())
+                           for _, name, _ in chunk) / fullw
+                useful += w * subw
+                padded += w * (nb - subw)
+            emitted += self._scatter_tail_rows(
+                [(sid, name) for sid, name, _ in chunk], outs, flush_id)
         return n_tail, emitted, useful, padded
 
     def flush(self) -> FlushReport:
@@ -1042,12 +1081,25 @@ class EMSServeEngine:
         (modality, bucket[, chunk]) per consuming model (ONE total with
         ``share_encoders``), scatter rows into the feature cache, one
         batched tail per selected model, emit progressive predictions,
-        sync the host ONCE."""
+        sync the host ONCE.
+
+        With a tracer, the ``flush`` span ``[t0, t1]`` holds disjoint
+        phase spans (cat ``flush``, each with ``flush_id`` and ``calls``,
+        the device array operations it issued: each bucketer fit, stack,
+        pack, row slice per array leaf, encoder and tail call once):
+        ``flush.prep`` (model selection, cache reads, pads, grouping,
+        stacks and packs), ``flush.encode`` and ``flush.tail`` (the
+        program calls), ``flush.scatter`` (row slices into the cache and
+        the emitted rows) and ``flush.sync`` (the one host sync);
+        ``flush.emit`` runs from ``t1`` to the return (predictions,
+        bookkeeping, metrics, eviction)."""
         if self.tiered:
             raise RuntimeError(
                 "flush() is a flush-mode operation; tiered placement "
                 "processes each arrival in submit()")
         t0 = self.time_fn()
+        flush_id = self.flushes_total
+        tr = self.tracer
         sync_targets = []
         # every dirty marking comes with a _pending entry, so only the
         # pending sessions can have work — never scan the whole (ever-
@@ -1056,107 +1108,112 @@ class EMSServeEngine:
 
         # ---- batched encode + scatter rows into the feature cache
         if self.ragged is not None:
-            n_enc, enc_u, enc_p = self._flush_encode_ragged(touched,
-                                                            sync_targets)
+            n_enc, enc_u, enc_p = self._flush_encode_ragged(
+                touched, sync_targets, flush_id)
         else:
-            n_enc, enc_u, enc_p = self._flush_encode(touched, sync_targets)
+            n_enc, enc_u, enc_p = self._flush_encode(touched, sync_targets,
+                                                     flush_id)
 
         # ---- progressive re-fusion: batched tails per selected model
-        tail_groups = defaultdict(list)    # model name -> [(sid, feats)]
-        consumed_meta: Dict[Tuple[str, str], dict] = {}
-        for sid in touched:
-            st = self.sessions[sid]
-            if not st.dirty:
-                continue
-            st.dirty.clear()
-            name = select_model(self.models, st.inputs)
-            if name is None:
-                continue
-            sm = self.models[name]
-            feats = self.cache.features(self._cache_key(st.sid, name),
-                                        sm.modalities(),
-                                        input_steps=st.input_step)
-            if feats is not None:
-                tail_groups[name].append((st.sid, feats))
-                if self.tracer:
-                    # snapshot source steps BEFORE the tail path
-                    # re-stamps them via cache.touch
-                    key = self._cache_key(st.sid, name)
-                    consumed_meta[(st.sid, name)] = {
-                        m: [self.cache.peek(key, m).step,
-                            st.input_step.get(m, 0)]
-                        for m in sm.modalities()}
-
-        full_name = (self._grouped_tail_target(tail_groups)
-                     if self.ragged is not None and tail_groups else None)
+        with tr.scope("flush.prep", "flush", flush_id=flush_id, calls=0):
+            tail_groups = defaultdict(list)    # model -> [(sid, feats)]
+            consumed_meta: Dict[Tuple[str, str], dict] = {}
+            for sid in touched:
+                st = self.sessions[sid]
+                if not st.dirty:
+                    continue
+                st.dirty.clear()
+                name = select_model(self.models, st.inputs)
+                if name is None:
+                    continue
+                sm = self.models[name]
+                feats = self.cache.features(self._cache_key(st.sid, name),
+                                            sm.modalities(),
+                                            input_steps=st.input_step)
+                if feats is not None:
+                    tail_groups[name].append((st.sid, feats))
+                    if tr:
+                        # snapshot source steps BEFORE the tail path
+                        # re-stamps them via cache.touch
+                        key = self._cache_key(st.sid, name)
+                        consumed_meta[(st.sid, name)] = {
+                            m: [self.cache.peek(key, m).step,
+                                st.input_step.get(m, 0)]
+                            for m in sm.modalities()}
+            full_name = (self._grouped_tail_target(tail_groups)
+                         if self.ragged is not None and tail_groups
+                         else None)
         if full_name is not None:
             n_tail, emitted, tail_u, tail_p = self._flush_tails_grouped(
-                tail_groups, full_name, sync_targets)
+                tail_groups, full_name, sync_targets, flush_id)
         else:
             n_tail, emitted, tail_u, tail_p = self._flush_tails(
-                tail_groups, sync_targets)
+                tail_groups, sync_targets, flush_id)
 
         # ---- the ONE host sync of this flush
-        jax.block_until_ready(sync_targets)
+        with tr.scope("flush.sync", "flush", flush_id=flush_id, calls=0):
+            jax.block_until_ready(sync_targets)
         t1 = self.time_fn()
 
-        flush_id = self.flushes_total
-        predictions, recommendations = [], {}
-        for sid, name, mods, row, step in emitted:
-            kind = "final" if frozenset(mods) == self.full_set else "partial"
-            pred = Prediction(sid=sid, step=step, model=name,
-                              modalities=mods, kind=kind, outputs=row,
-                              flush_id=flush_id, t_emit=t1)
-            st = self.sessions[sid]
-            self._record_prediction(st, pred)
-            predictions.append(pred)
-            recommendations[sid] = row
-            if self.tracer:
-                key = self._cache_key(sid, name)
-                self.tracer.instant(
-                    "fuse", "fusion", t1, track=f"session:{sid}",
-                    sid=sid, key=key, model=name, step=step,
-                    consumed=consumed_meta.get((sid, name), {}))
-                self.tracer.instant(
-                    "emit", "predict", t1, track=f"session:{sid}",
-                    sid=sid, key=key, model=name, step=step, kind=kind,
-                    modalities=sorted(mods))
+        with tr.scope("flush.emit", "flush", at=t1, flush_id=flush_id,
+                      calls=0):
+            predictions, recommendations = [], {}
+            for sid, name, mods, row, step in emitted:
+                kind = ("final" if frozenset(mods) == self.full_set
+                        else "partial")
+                pred = Prediction(sid=sid, step=step, model=name,
+                                  modalities=mods, kind=kind, outputs=row,
+                                  flush_id=flush_id, t_emit=t1)
+                st = self.sessions[sid]
+                self._record_prediction(st, pred)
+                predictions.append(pred)
+                recommendations[sid] = row
+                if tr:
+                    key = self._cache_key(sid, name)
+                    tr.instant(
+                        "fuse", "fusion", t1, track=f"session:{sid}",
+                        sid=sid, key=key, model=name, step=step,
+                        consumed=consumed_meta.get((sid, name), {}))
+                    tr.instant(
+                        "emit", "predict", t1, track=f"session:{sid}",
+                        sid=sid, key=key, model=name, step=step, kind=kind,
+                        modalities=sorted(mods))
 
-        # keyed by arrival with the EARLIEST submit kept: a duplicate
-        # submission of the same (sid, idx) used to overwrite the first
-        # latency entry and double-count n_events
-        arrived: Dict[Tuple[str, int], float] = {}
-        for sid, idx, ts in self._pending:
-            arrived.setdefault((sid, idx), ts)
-        latencies = {key: t1 - ts for key, ts in arrived.items()}
-        report = FlushReport(
-            flush_id=flush_id, n_events=len(arrived),
-            n_encoder_calls=n_enc, n_tail_calls=n_tail, wall_s=t1 - t0,
-            latencies=latencies, predictions=predictions,
-            recommendations=recommendations,
-            flops_useful=enc_u + tail_u, flops_padded=enc_p + tail_p)
-        if self.tracer:
-            for (sid, idx), ts in arrived.items():
-                self.tracer.span("queue.wait", "queue", ts, t0,
-                                 track=f"session:{sid}", sid=sid,
-                                 index=idx)
-            self.tracer.span("flush", "flush", t0, t1, track="engine",
-                             flush_id=flush_id, n_events=len(arrived),
-                             n_encoder_calls=n_enc, n_tail_calls=n_tail)
-        self.metrics.inc("engine.flushes")
-        self.metrics.inc("engine.flush_events", len(arrived))
-        self.metrics.observe("flush.wall_s", t1 - t0)
-        for lat in latencies.values():
-            self.metrics.observe("serve.latency_s", lat)
-        self._pending.clear()
-        self.flushes.append(report)
-        if self.max_history is not None:
-            del self.flushes[:-self.max_history]
-        self.flushes_total += 1
-        self._enc_calls_total += n_enc
-        self._tail_calls_total += n_tail
-        self.evict_sessions(t1)
-        return report
+            # keyed by arrival with the EARLIEST submit kept: a duplicate
+            # submission of the same (sid, idx) used to overwrite the
+            # first latency entry and double-count n_events
+            arrived: Dict[Tuple[str, int], float] = {}
+            for sid, idx, ts in self._pending:
+                arrived.setdefault((sid, idx), ts)
+            latencies = {key: t1 - ts for key, ts in arrived.items()}
+            report = FlushReport(
+                flush_id=flush_id, n_events=len(arrived),
+                n_encoder_calls=n_enc, n_tail_calls=n_tail,
+                wall_s=t1 - t0, latencies=latencies,
+                predictions=predictions, recommendations=recommendations,
+                flops_useful=enc_u + tail_u, flops_padded=enc_p + tail_p)
+            if tr:
+                for (sid, idx), ts in arrived.items():
+                    tr.span("queue.wait", "queue", ts, t0,
+                            track=f"session:{sid}", sid=sid, index=idx,
+                            flush_id=flush_id)
+                tr.span("flush", "flush", t0, t1, track="engine",
+                        flush_id=flush_id, n_events=len(arrived),
+                        n_encoder_calls=n_enc, n_tail_calls=n_tail)
+            self.metrics.inc("engine.flushes")
+            self.metrics.inc("engine.flush_events", len(arrived))
+            self.metrics.observe("flush.wall_s", t1 - t0)
+            for lat in latencies.values():
+                self.metrics.observe("serve.latency_s", lat)
+            self._pending.clear()
+            self.flushes.append(report)
+            if self.max_history is not None:
+                del self.flushes[:-self.max_history]
+            self.flushes_total += 1
+            self._enc_calls_total += n_enc
+            self._tail_calls_total += n_tail
+            self.evict_sessions(t1)
+            return report
 
     def _record_prediction(self, st: SessionView, pred: Prediction):
         """Session-side bookkeeping shared by flush- and tiered-mode

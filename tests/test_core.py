@@ -55,6 +55,24 @@ def test_split_equals_full(tiny_models):
         np.testing.assert_allclose(via_split[k], via_full[k], atol=1e-5)
 
 
+def test_split_names_each_program(tiny_models):
+    """Every piece lowers under its own name (compile logs and the
+    device trace's module line tell them apart), not ``jit__lambda``."""
+    cfg, splits, params = tiny_models
+    batch = _payloads(cfg)
+    sm, p = splits["m2"], params["m2"]
+    feats = {m: sm.encoders[m](p, batch[m]) for m in sm.modalities()}
+    names = {
+        "jit_encode_text": sm.encoders["text"].lower(p, batch["text"]),
+        "jit_encode_vitals": sm.encoders["vitals"].lower(p, batch["vitals"]),
+        "jit_tail_text_vitals": sm.tail.lower(p, feats),
+        "jit_full_text_vitals": sm.full.lower(
+            p, {m: batch[m] for m in sm.modalities()}),
+    }
+    for name, lowered in names.items():
+        assert lowered.as_text().startswith(f"module @{name} "), name
+
+
 class _FakeSplit:
     """select_model only needs .modalities()."""
     def __init__(self, *mods):

@@ -552,3 +552,127 @@ def test_sketch_boundary_value_keeps_error_bound():
     assert abs(got - v) <= s.rel_err * v
     # structural pin: the boundary value sits in bucket i, not i+1
     assert s._buckets.get(16) == 1
+
+
+# ================================================ Tracer.scope, flush phases
+
+def _ticks(start=1.0, step=0.001):
+    """A fake clock: every reading is ``step`` later than the last."""
+    return itertools.count(start, step).__next__
+
+
+def test_scope_records_one_span_with_its_args(tmp_path):
+    tr = Tracer(clock=_ticks())
+    with tr.scope("work", "c", track="t", a=1) as sc:
+        sc.set(b=2)
+    with tr.scope("late", "c", at=0.5):
+        pass
+    (work, late) = tr.events
+    assert (work.name, work.cat, work.track) == ("work", "c", "t")
+    assert (work.ts, work.dur) == pytest.approx((1.0, 0.001))
+    assert work.args == {"a": 1, "b": 2}
+    assert late.ts == 0.5 and late.dur == pytest.approx(1.002 - 0.5)
+    # the streaming tracer inherits it and spills what it records
+    st = StreamingTracer(tmp_path / "s.jsonl", buffer=1, clock=_ticks())
+    with st.scope("work", "c"):
+        pass
+    assert st.events_written == 1
+    st.close()
+
+
+def test_scope_of_the_disabled_tracer_is_one_shared_noop():
+    a = Tracer.disabled.scope("x", "c", flush_id=0)
+    with a as sc:
+        sc.set(calls=3)
+    assert a is Tracer.disabled.scope("y", "c")
+    assert Tracer.disabled.events == []
+
+
+PHASES = ("flush.prep", "flush.encode", "flush.scatter", "flush.tail",
+          "flush.sync", "flush.emit")
+
+
+def _two_session_flush(zoo_models, *, ragged, tracer):
+    """s0 sends text and vitals, s1 text; one flush takes all three."""
+    cfg, splits, shared, params, payloads = zoo_models
+    eng = build_engine(splits, params, "batch+stream", share_encoders=True,
+                       ragged=ragged, deadline_s=None, max_history=None,
+                       tracer=tracer, time_fn=_ticks())
+    eng.submit("s0", Event(0, "text", 0.0), payloads["text"])
+    eng.submit("s0", Event(1, "vitals", 0.0), payloads["vitals"])
+    eng.submit("s1", Event(0, "text", 0.0), payloads["text"])
+    return eng, eng.drain()
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_flush_phases_are_disjoint_inside_the_flush_span(zoo_models,
+                                                          ragged):
+    eng, rep = _two_session_flush(zoo_models, ragged=ragged,
+                                  tracer=Tracer())
+    evs = eng.tracer.events
+    (flush,) = [e for e in evs if e.name == "flush"]
+    phases = sorted((e for e in evs if e.name in PHASES),
+                    key=lambda e: e.ts)
+    assert {e.name for e in phases} == set(PHASES)
+    assert all(e.cat == "flush" and e.track == "engine"
+               and e.args["flush_id"] == rep.flush_id == 0
+               for e in phases)
+    t0, t1 = flush.ts, flush.ts + flush.dur
+    assert flush.dur == pytest.approx(rep.wall_s)
+    for a, b in zip(phases, phases[1:]):
+        assert a.ts + a.dur <= b.ts
+    *inside, emit = phases
+    assert emit.name == "flush.emit" and emit.ts == t1
+    assert all(t0 <= e.ts and e.ts + e.dur <= t1 for e in inside)
+    waits = [e for e in evs if e.name == "queue.wait"]
+    assert len(waits) == 3
+    assert all(e.args["flush_id"] == rep.flush_id for e in waits)
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_flush_phase_calls_match_a_hand_count(zoo_models, ragged):
+    eng, rep = _two_session_flush(zoo_models, ragged=ragged,
+                                  tracer=Tracer())
+    leaves = len(jax.tree_util.tree_leaves(rep.predictions[0].outputs))
+    calls = {}
+    for e in eng.tracer.events:
+        if e.name in PHASES:
+            calls[e.name] = calls.get(e.name, 0) + e.args["calls"]
+    if ragged:
+        # one pack per text and vitals chunk, then ONE grouped tail
+        # over both sessions: a stack per modality of the full model
+        want = {"flush.prep": 2 + 3, "flush.encode": 2, "flush.tail": 1,
+                "flush.scatter": 2 + 1 + 2 * leaves}
+    else:
+        # 3 bucketer fits, a stack per encoder chunk (text, vitals), then
+        # the text+vitals tail (2 stacks) and the text tail (1 stack)
+        want = {"flush.prep": 3 + 2 + 2 + 1, "flush.encode": 2,
+                "flush.tail": 2, "flush.scatter": 2 + 1 + 2 * leaves}
+    assert calls == dict(want, **{"flush.sync": 0, "flush.emit": 0})
+    assert calls["flush.encode"] == rep.n_encoder_calls
+    assert calls["flush.tail"] == rep.n_tail_calls
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_flush_phases_change_no_output_and_cost_nothing_off(zoo_models,
+                                                            ragged):
+    _, on = _two_session_flush(zoo_models, ragged=ragged, tracer=Tracer())
+    eng, off = _two_session_flush(zoo_models, ragged=ragged, tracer=None)
+    assert eng.tracer is Tracer.disabled and Tracer.disabled.events == []
+    assert [(p.sid, p.model, p.step) for p in on.predictions] == \
+        [(p.sid, p.model, p.step) for p in off.predictions]
+    for a, b in zip(on.predictions, off.predictions):
+        for k in a.outputs:
+            np.testing.assert_array_equal(a.outputs[k], b.outputs[k])
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_trace_with_flush_phases_passes_the_audit(zoo_models, ragged,
+                                                  tmp_path):
+    eng, _ = _two_session_flush(zoo_models, ragged=ragged, tracer=Tracer())
+    p = tmp_path / "flush.json"
+    eng.tracer.export(p)
+    assert validate_chrome(json.loads(p.read_text())) == []
+    rep = audit_file(p)
+    assert rep.ok, rep.violations
+    assert rep.checks["fuses"] == rep.checks["emits"] == 2
