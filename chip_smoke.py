@@ -328,20 +328,28 @@ def run_fleet(args, devs):
         if a is None or b is None:
             fail("fleet", f"{s.sid} has no final")
         r = sim4.route_of[s.sid]
+        # the finals are host rows; the session's cached features show
+        # where its encoders ran, and the tail ran beside them
+        feats = [e.feature for (key, _), e in sim4.replicas[r].cache.entries()
+                 if key == s.sid]
+        if not feats:
+            fail("fleet", f"{s.sid} has no cached features on replica {r}")
+        for f in feats:
+            if f.devices() != {devs[r]}:
+                fail("fleet", f"{s.sid} on replica {r}: features on "
+                              f"{f.devices()}, not {devs[r]}")
+            used |= f.devices()
         for k in OUTPUT_KEYS:
-            if a[k].devices() != {devs[r]}:
-                fail("fleet", f"{s.sid} on replica {r}: output on "
-                              f"{a[k].devices()}, not {devs[r]}")
-            used |= a[k].devices()
             worst = max(worst, float(np.abs(np.asarray(a[k])
                                             - np.asarray(b[k])).max()))
     if len(used) != 4:
-        fail("fleet", f"the finals sit on {len(used)} devices, not 4")
+        fail("fleet", f"the sessions' features sit on {len(used)} devices, "
+                      "not 4")
     if not worst <= LOGIT_ATOL:
         fail("fleet", f"4- vs 1-replica finals: max |d| {worst:.6g} > "
                       f"{LOGIT_ATOL}")
     phase("fleet", f"{len(sessions)} sessions, all final on both; 4 "
-                   f"replicas' finals on {len(used)} distinct devices, "
+                   f"replicas' features on {len(used)} distinct devices, "
                    f"serving {served} sessions; finals "
                    f"vs 1 replica max|d| {worst:.3e} (atol {LOGIT_ATOL}); "
                    f"flushes 4x {sum(p['flushes'] for p in rep4['per_replica'])}"

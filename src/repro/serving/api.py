@@ -125,15 +125,18 @@ class Prediction:
     """One progressive prediction emitted for a session.
 
     Flush-mode predictions carry the flush that produced them in
-    ``flush_id``; tiered-mode (per-arrival) predictions carry ``-1``
-    there and stamp ``t_emit`` on the simulated tier clock instead of
-    the engine's ``time_fn``."""
+    ``flush_id``, and ``outputs`` as host ``numpy`` rows of shape
+    ``(1, k)``: the flush fetches each tail call's whole output once and
+    cuts the rows on the host. Tiered-mode (per-arrival) predictions
+    carry ``-1`` there, keep their outputs as the tier's arrays, and
+    stamp ``t_emit`` on the simulated tier clock instead of the engine's
+    ``time_fn``."""
     sid: str
     step: int                       # session step it reflects
     model: str                      # selected model name
     modalities: Tuple[str, ...]     # fused subset, canonical order
     kind: str                       # "partial" | "final"
-    outputs: dict                   # head outputs (batch row for sid)
+    outputs: dict                   # head outputs (the sid's row)
     flush_id: int
     t_emit: float
 
@@ -144,7 +147,7 @@ class FlushReport:
     host sync's wall time, per-arrival latencies, and the emissions —
     ``predictions`` (tagged partial/final) and the last fused head
     outputs per touched session in ``recommendations`` (the batch-mode
-    contract; identical rows, different indexing)."""
+    contract; identical host rows, different indexing)."""
     flush_id: int
     n_events: int
     n_encoder_calls: int
@@ -968,9 +971,11 @@ class EMSServeEngine:
         return n_enc, useful, padded
 
     def _flush_tails(self, tail_groups, sync_targets, flush_id):
-        """One batched tail call per selected model (per chunk)."""
-        n_tail, useful, padded = 0, 0.0, 0.0
-        emitted = []      # (sid, name, modalities, outputs, step)
+        """One batched tail call per selected model (per chunk). Returns
+        the calls' outputs, the rows to emit from them, and the padding
+        tax."""
+        useful, padded = 0.0, 0.0
+        tail_outs, to_emit = [], []
         for name, items in tail_groups.items():
             sm = self.models[name]
             mods = sm.modalities()
@@ -986,32 +991,32 @@ class EMSServeEngine:
                 with self.tracer.scope("flush.tail", "flush",
                                        flush_id=flush_id, calls=1):
                     outs = sm.tail(self.params[name], stacked)
-                    n_tail += 1
                     rows = next(iter(stacked.values())).shape[0]
                     useful += w * len(chunk)
                     padded += w * (rows - len(chunk))
                     sync_targets.append(outs)
-                emitted += self._scatter_tail_rows(
-                    [(sid, name) for sid, _ in chunk], outs, flush_id)
-        return n_tail, emitted, useful, padded
+                    tail_outs.append(outs)
+                to_emit += self._scatter_tail_rows(
+                    [(sid, name) for sid, _ in chunk], len(tail_outs) - 1,
+                    flush_id)
+        return tail_outs, to_emit, useful, padded
 
-    def _scatter_tail_rows(self, chunk, outs, flush_id):
-        """Slice one tail call's outputs into per-session rows and
-        re-stamp the cache entries each row consumed. ``chunk`` lists
-        (sid, model name) per row; returns the emitted entries."""
-        emitted = []
-        n_leaves = len(jax.tree_util.tree_leaves(outs))
+    def _scatter_tail_rows(self, chunk, call, flush_id):
+        """Re-stamp the cache entries each row of tail call ``call``
+        consumed. ``chunk`` lists (sid, model name) per row; returns the
+        rows to emit as (sid, name, modalities, call, row index, step).
+        The rows' outputs are cut on the host after the sync."""
+        to_emit = []
         with self.tracer.scope("flush.scatter", "flush", flush_id=flush_id,
-                               calls=len(chunk) * n_leaves):
+                               calls=0):
             for i, (sid, name) in enumerate(chunk):
                 st = self.sessions[sid]
-                row = jax.tree.map(lambda a: a[i:i + 1], outs)
                 mods = self.models[name].modalities()
-                emitted.append((sid, name, tuple(mods), row, st.step))
+                to_emit.append((sid, name, tuple(mods), call, i, st.step))
                 for mm in mods:   # the result carries the cache back
                     self.cache.touch(self._cache_key(sid, name), mm,
                                      st.step)
-        return emitted
+        return to_emit
 
     def _grouped_tail_target(self, tail_groups) -> Optional[str]:
         """The ONE grouped tail is legal when a full-fusion model exists,
@@ -1050,8 +1055,8 @@ class EMSServeEngine:
         rows = [(sid, name, f)
                 for name, items in tail_groups.items()
                 for sid, f in items]
-        n_tail, useful, padded = 0, 0.0, 0.0
-        emitted = []
+        useful, padded = 0.0, 0.0
+        tail_outs, to_emit = [], []
         for c0 in range(0, len(rows), self.max_coalesce):
             chunk = rows[c0:c0 + self.max_coalesce]
             nb = self._bucket_rows(len(chunk))
@@ -1065,16 +1070,17 @@ class EMSServeEngine:
             with self.tracer.scope("flush.tail", "flush", flush_id=flush_id,
                                    calls=1):
                 outs = full_sm.tail(self.params[full_name], stacked)
-                n_tail += 1
                 sync_targets.append(outs)
+                tail_outs.append(outs)
                 subw = sum(sum(dims[m]
                                for m in self.models[name].modalities())
                            for _, name, _ in chunk) / fullw
                 useful += w * subw
                 padded += w * (nb - subw)
-            emitted += self._scatter_tail_rows(
-                [(sid, name) for sid, name, _ in chunk], outs, flush_id)
-        return n_tail, emitted, useful, padded
+            to_emit += self._scatter_tail_rows(
+                [(sid, name) for sid, name, _ in chunk], len(tail_outs) - 1,
+                flush_id)
+        return tail_outs, to_emit, useful, padded
 
     def flush(self) -> FlushReport:
         """Run all pending work: one batched encoder call per
@@ -1086,13 +1092,16 @@ class EMSServeEngine:
         With a tracer, the ``flush`` span ``[t0, t1]`` holds disjoint
         phase spans (cat ``flush``, each with ``flush_id`` and ``calls``,
         the device array operations it issued: each bucketer fit, stack,
-        pack, row slice per array leaf, encoder and tail call once):
-        ``flush.prep`` (model selection, cache reads, pads, grouping,
-        stacks and packs), ``flush.encode`` and ``flush.tail`` (the
-        program calls), ``flush.scatter`` (row slices into the cache and
-        the emitted rows) and ``flush.sync`` (the one host sync);
-        ``flush.emit`` runs from ``t1`` to the return (predictions,
-        bookkeeping, metrics, eviction)."""
+        pack, feature row slice, encoder and tail call, and tail output
+        leaf fetched once): ``flush.prep`` (model
+        selection, cache reads, pads, grouping, stacks and packs),
+        ``flush.encode`` and ``flush.tail`` (the program calls),
+        ``flush.scatter`` (row slices into the cache, the rows'
+        bookkeeping, and, after the sync, the one fetch of every tail
+        output to the host with ``fetched`` leaves, where the emitted rows
+        are cut) and ``flush.sync`` (the one host sync); ``flush.emit``
+        runs from ``t1`` to the return (predictions, bookkeeping, metrics,
+        eviction). ``t1`` is when the host holds the emitted numbers."""
         if self.tiered:
             raise RuntimeError(
                 "flush() is a flush-mode operation; tiered placement "
@@ -1144,15 +1153,25 @@ class EMSServeEngine:
                          if self.ragged is not None and tail_groups
                          else None)
         if full_name is not None:
-            n_tail, emitted, tail_u, tail_p = self._flush_tails_grouped(
+            tail_outs, to_emit, tail_u, tail_p = self._flush_tails_grouped(
                 tail_groups, full_name, sync_targets, flush_id)
         else:
-            n_tail, emitted, tail_u, tail_p = self._flush_tails(
+            tail_outs, to_emit, tail_u, tail_p = self._flush_tails(
                 tail_groups, sync_targets, flush_id)
+        n_tail = len(tail_outs)
 
         # ---- the ONE host sync of this flush
         with tr.scope("flush.sync", "flush", flush_id=flush_id, calls=0):
             jax.block_until_ready(sync_targets)
+        # ---- every tail output to the host in one fetch; each emitted
+        # row is a numpy view of its call's output
+        fetched = len(jax.tree_util.tree_leaves(tail_outs))
+        with tr.scope("flush.scatter", "flush", flush_id=flush_id,
+                      calls=fetched, fetched=fetched):
+            host = jax.device_get(tail_outs)
+            emitted = [(sid, name, mods,
+                        jax.tree.map(lambda a: a[i:i + 1], host[call]), step)
+                       for sid, name, mods, call, i, step in to_emit]
         t1 = self.time_fn()
 
         with tr.scope("flush.emit", "flush", at=t1, flush_id=flush_id,
@@ -1202,6 +1221,7 @@ class EMSServeEngine:
                         n_encoder_calls=n_enc, n_tail_calls=n_tail)
             self.metrics.inc("engine.flushes")
             self.metrics.inc("engine.flush_events", len(arrived))
+            self.metrics.inc("engine.rows_host", len(emitted))
             self.metrics.observe("flush.wall_s", t1 - t0)
             for lat in latencies.values():
                 self.metrics.observe("serve.latency_s", lat)

@@ -17,6 +17,7 @@ Three claims under test:
     and tampering with a trace (version skip, unstamped fuse input,
     cancel-after-deliver, emit without fuse) is caught.
 """
+import dataclasses
 import itertools
 import json
 
@@ -592,9 +593,20 @@ PHASES = ("flush.prep", "flush.encode", "flush.scatter", "flush.tail",
           "flush.sync", "flush.emit")
 
 
-def _two_session_flush(zoo_models, *, ragged, tracer):
-    """s0 sends text and vitals, s1 text; one flush takes all three."""
+def _two_session_flush(zoo_models, *, ragged, tracer, tail_outs=None):
+    """s0 sends text and vitals, s1 text; one flush takes all three.
+    Where ``tail_outs`` is a list, every tail call's whole output is
+    appended to it."""
     cfg, splits, shared, params, payloads = zoo_models
+    if tail_outs is not None:
+        def spy(tail):
+            def call(p, feats):
+                out = tail(p, feats)
+                tail_outs.append(out)
+                return out
+            return call
+        splits = {k: dataclasses.replace(sm, tail=spy(sm.tail))
+                  for k, sm in splits.items()}
     eng = build_engine(splits, params, "batch+stream", share_encoders=True,
                        ragged=ragged, deadline_s=None, max_history=None,
                        tracer=tracer, time_fn=_ticks())
@@ -638,19 +650,61 @@ def test_flush_phase_calls_match_a_hand_count(zoo_models, ragged):
     for e in eng.tracer.events:
         if e.name in PHASES:
             calls[e.name] = calls.get(e.name, 0) + e.args["calls"]
+    # scatter: a feature row per text (2) and vitals (1) input, then
+    # each tail call's output leaves fetched once, not a slice per row
     if ragged:
         # one pack per text and vitals chunk, then ONE grouped tail
         # over both sessions: a stack per modality of the full model
         want = {"flush.prep": 2 + 3, "flush.encode": 2, "flush.tail": 1,
-                "flush.scatter": 2 + 1 + 2 * leaves}
+                "flush.scatter": 3 + rep.n_tail_calls * leaves}
     else:
         # 3 bucketer fits, a stack per encoder chunk (text, vitals), then
         # the text+vitals tail (2 stacks) and the text tail (1 stack)
         want = {"flush.prep": 3 + 2 + 2 + 1, "flush.encode": 2,
-                "flush.tail": 2, "flush.scatter": 2 + 1 + 2 * leaves}
+                "flush.tail": 2,
+                "flush.scatter": 3 + rep.n_tail_calls * leaves}
     assert calls == dict(want, **{"flush.sync": 0, "flush.emit": 0})
     assert calls["flush.encode"] == rep.n_encoder_calls
     assert calls["flush.tail"] == rep.n_tail_calls
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_flush_predictions_are_host_rows_of_the_whole_tail_output(
+        zoo_models, ragged):
+    outs = []
+    eng, rep = _two_session_flush(zoo_models, ragged=ragged, tracer=None,
+                                  tail_outs=outs)
+    assert len(outs) == rep.n_tail_calls
+    # (tail call, row) of s0 and s1: two per-model calls of one row each,
+    # or the ONE grouped call over both rows
+    where = [(0, 0), (0, 1)] if ragged else [(0, 0), (1, 0)]
+    assert [p.sid for p in rep.predictions] == ["s0", "s1"]
+    for p, (call, i) in zip(rep.predictions, where):
+        assert rep.recommendations[p.sid] is p.outputs
+        assert set(p.outputs) == set(outs[call])
+        for k, v in p.outputs.items():
+            whole = np.asarray(outs[call][k])
+            assert type(v) is np.ndarray
+            assert v.shape == (1,) + whole.shape[1:]
+            np.testing.assert_allclose(v, whole[i:i + 1], rtol=0, atol=0)
+    assert eng.metrics.get("engine.rows_host") == len(rep.predictions)
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_output_fetch_follows_the_sync_inside_the_flush(zoo_models, ragged):
+    eng, rep = _two_session_flush(zoo_models, ragged=ragged,
+                                  tracer=Tracer())
+    evs = eng.tracer.events
+    (flush,) = [e for e in evs if e.name == "flush"]
+    (sync,) = [e for e in evs if e.name == "flush.sync"]
+    (fetch,) = [e for e in evs
+                if e.name == "flush.scatter" and "fetched" in e.args]
+    leaves = len(jax.tree_util.tree_leaves(rep.predictions[0].outputs))
+    assert fetch.args["fetched"] == fetch.args["calls"] \
+        == rep.n_tail_calls * leaves
+    assert sync.ts + sync.dur <= fetch.ts
+    assert fetch.ts + fetch.dur <= flush.ts + flush.dur
+    assert all(p.t_emit == flush.ts + flush.dur for p in rep.predictions)
 
 
 @pytest.mark.parametrize("ragged", [False, True])
