@@ -37,13 +37,14 @@ def main(argv=None) -> int:
     for seed in [int(s) for s in args.seeds.split(",")]:
         seen = {}
 
-        def on_check(params, model, rows, ref, got):
+        def on_check(family, params, model, rows, ref, got):
             # the reference at the highest and at the default matmul
             # precision, the program and the bfloat16 control against each
-            refs = {p: reference.forward(params, model, rows, precision=p)
+            refs = {p: reference.forward(family, params, model, rows,
+                                         precision=p)
                     for p in ("highest", "default")}
-            low = reference.forward(params, model, rows, dtype=jnp.bfloat16,
-                                    precision="default")
+            low = reference.forward(family, params, model, rows,
+                                    dtype=jnp.bfloat16, precision="default")
             for name, r in refs.items():
                 seen[f"program_vs_{name}"] = _numbers(got, r)
                 seen[f"control_vs_{name}"] = _numbers(low, r)

@@ -8,9 +8,10 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Dict, List, Optional
 
-from . import flops, stats, trace
+from . import stats, trace
 from .window import Window, end_to_end
 
 
@@ -19,6 +20,7 @@ class Context:
     win: Window
     model: dict
     peaks: dict
+    family: Optional[ModuleType] = None            # bench/families/<name>
     spans: List = field(default_factory=list)      # the program's Tracer
     trace: Optional[trace.Trace] = None
 
@@ -80,27 +82,29 @@ def encoded_inputs(ctx: Context, reports) -> List[tuple]:
     return out
 
 
+FLASH = "flash_attention"       # the flash kernel's op name in the trace
+
+
 def flash_roofline(ctx: Context) -> Optional[float]:
     """Least time the chip needs for the window's useful attention work
     (natural lengths; the larger of its FLOPs over the bf16 peak and its
-    Q/K/V/O bytes over HBM bandwidth), over the flash kernel's device
-    time in the window. The flash kernel is the only Pallas kernel on
-    the float32 path, so the trace's Pallas time is its time. A window
-    that encoded text while the trace shows no Pallas time is an error:
-    the kernel went unseen."""
+    bytes over HBM bandwidth, as the family's ``kernel_work`` counts
+    them), over the device time of the Pallas ops named ``FLASH`` in the
+    window. A window that encoded text while the trace shows no such op
+    is an error: the kernel went unseen."""
     if ctx.trace is None:
         return None
     pf, pb = ctx.peaks["bf16_flops_per_s"], ctx.peaks["hbm_bytes_per_s"]
-    least = sum(max(flops.attention_flops(n, ctx.model) / pf,
-                    flops.attention_bytes(n, ctx.model) / pb)
-                for mod, n in encoded_inputs(ctx, window_flushes(ctx))
-                if mod == "text")
+    work = (ctx.family.kernel_work(FLASH, n, ctx.model)
+            for mod, n in encoded_inputs(ctx, window_flushes(ctx))
+            if mod == "text")
+    least = sum(max(f / pf, b / pb) for f, b in work)
     if least <= 0:
         return None
-    kernel_s = trace.kernel_seconds(ctx.trace)
+    kernel_s = trace.kernel_seconds(ctx.trace, FLASH)
     if kernel_s <= 0:
         raise ValueError("the window encoded text, but the trace holds no "
-                         f"{trace.KERNEL_TARGET} operation")
+                         f"{FLASH} {trace.KERNEL_TARGET} operation")
     return 100.0 * least / kernel_s
 
 
@@ -112,15 +116,15 @@ def idle_share(ctx: Context) -> Optional[float]:
 
 def flush_mfu(ctx: Context) -> Optional[float]:
     """Useful model FLOPs of the window's flushes (encoders at natural
-    lengths, heads per prediction) over their summed wall time at the
-    bf16 peak."""
+    lengths, heads per prediction, as the family counts them) over their
+    summed wall time at the bf16 peak."""
     fl = window_flushes(ctx)
     wall = sum(r.wall_s for r in fl)
     if wall <= 0:
         return None
-    work = sum(flops.encoder_flops(mod, n, ctx.model)
+    work = sum(ctx.family.encoder_flops(mod, n, ctx.model)
                for mod, n in encoded_inputs(ctx, fl))
-    work += sum(flops.head_flops(p.modalities, ctx.model)
+    work += sum(ctx.family.head_flops(p.modalities, ctx.model)
                 for r in fl for p in r.predictions)
     return 100.0 * work / (wall * ctx.peaks["bf16_flops_per_s"])
 

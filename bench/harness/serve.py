@@ -18,32 +18,11 @@ import numpy as np
 from .gen import Schedule
 
 
-def program_config(model: dict):
-    """The program's ``EMSNetConfig`` for a configuration file's sizes;
-    refuses a file whose widths the program's text-encoder table would
-    not give."""
-    from repro.configs.emsnet import EMSNetConfig
-    cfg = EMSNetConfig(
-        text_encoder=model["text_encoder"], vocab_size=model["vocab_size"],
-        max_text_len=model["max_text_len"],
-        vitals_encoder=model["vitals_encoder"], n_vitals=model["n_vitals"],
-        vitals_len=model["vitals_len"], vitals_hidden=model["vitals_hidden"],
-        scene_dim=model["scene_dim"], scene_hidden=model["scene_hidden"],
-        n_protocols=model["n_protocols"], n_medicines=model["n_medicines"],
-        dtype=model["dtype"], use_flash_text=model["use_flash_text"],
-        flash_block=model["flash_block"])
-    want = (model["text_layers"], model["text_hidden"], model["text_heads"],
-            model["text_ffn"])
-    if tuple(cfg.text_dims) != want:
-        raise ValueError(f"the program's {model['text_encoder']!r} text "
-                         f"encoder is {cfg.text_dims}, the file says {want}")
-    return cfg
-
-
-def build_zoo(model: dict):
-    """The 7 subset models over one parameter pytree (uncompiled)."""
+def build_zoo(family, model: dict):
+    """The 7 subset models over one parameter pytree (uncompiled), for
+    the program's config that the configuration's family gives."""
     from repro.core import emsnet_zoo, split
-    zoo = emsnet_zoo(program_config(model))
+    zoo = emsnet_zoo(family.program_config(model))
     return {k: split(m) for k, m in zoo.items()}
 
 

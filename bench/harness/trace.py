@@ -83,12 +83,12 @@ def op_seconds(tr: Trace) -> Dict[str, float]:
     return out
 
 
-def kernel_seconds(tr: Trace) -> float:
-    """Device seconds of Pallas kernels inside the window, summed over
-    devices."""
+def kernel_seconds(tr: Trace, name: str) -> float:
+    """Device seconds of the Pallas kernel whose op name is ``name``
+    inside the window, summed over devices."""
     lo, hi = tr.window
     return sum(max(0.0, min(e, hi) - max(s, lo)) * 1e-9
-               for ops in tr.kernels for _, s, e in ops)
+               for ops in tr.kernels for op, s, e in ops if op == name)
 
 
 def _is_kernel(ev) -> bool:

@@ -1,9 +1,10 @@
-"""EMSNet weights made on the device from the seed, in one jitted call.
+"""Weights made from the seed: the key every family draws from, and the
+draws of a dense layer and a LayerNorm.
 
-The pytree has the layout the program's encoders read (the program's
-parameter format is its interface); the values are this file's own.
-Every bias and norm parameter is random, not zero or one, so that a
-program which drops one differs from the reference.
+Each family (``bench/families``) lays out its own pytree and makes it on
+the device in one jitted call. Every bias and norm parameter is random,
+not zero or one, so that a program which drops one differs from the
+reference.
 """
 from __future__ import annotations
 
@@ -18,7 +19,9 @@ def jax_key(seed: int, tag: int) -> jax.Array:
     return jax.random.PRNGKey(int(r.integers(0, 2**31 - 1)))
 
 
-def _dense(key, d_in, d_out, bias=True):
+def dense(key, d_in, d_out, bias=True):
+    """A dense layer's ``w`` (d_in, d_out), scaled by 1/sqrt(d_in), and
+    its bias ``b``."""
     kw, kb = jax.random.split(key)
     p = {"w": jax.random.normal(kw, (d_in, d_out), jnp.float32)
          / np.sqrt(d_in)}
@@ -27,44 +30,8 @@ def _dense(key, d_in, d_out, bias=True):
     return p
 
 
-def _norm(key, d):
+def norm(key, d):
+    """A LayerNorm's ``scale`` about 1 and its ``bias`` about 0."""
     ks, kb = jax.random.split(key)
     return {"scale": 1.0 + 0.1 * jax.random.normal(ks, (d,), jnp.float32),
             "bias": 0.02 * jax.random.normal(kb, (d,), jnp.float32)}
-
-
-def _init(key, m: dict):
-    L, d, ff = m["text_layers"], m["text_hidden"], m["text_ffn"]
-    h = m["vitals_hidden"]
-    ks = iter(jax.random.split(key, 16 + 6 * L))
-    fc = d + h + m["scene_hidden"]
-    return {
-        "text": {
-            "tok": {"emb": 0.02 * jax.random.normal(
-                next(ks), (m["vocab_size"], d), jnp.float32)},
-            "pos": {"emb": 0.02 * jax.random.normal(
-                next(ks), (m["max_text_len"], d), jnp.float32)},
-            "ln": _norm(next(ks), d),
-            "blocks": [{"ln1": _norm(next(ks), d),
-                        "wqkv": _dense(next(ks), d, 3 * d),
-                        "wo": _dense(next(ks), d, d),
-                        "ln2": _norm(next(ks), d),
-                        "w1": _dense(next(ks), d, ff),
-                        "w2": _dense(next(ks), ff, d)} for _ in range(L)],
-        },
-        "vitals": {"wx": _dense(next(ks), m["n_vitals"], 3 * h),
-                   "wh": _dense(next(ks), h, 3 * h, bias=False)},
-        "scene": {"fc": _dense(next(ks), m["scene_dim"], m["scene_hidden"])},
-        "heads": {"protocol": _dense(next(ks), fc, m["n_protocols"]),
-                  "medicine": _dense(next(ks), fc, m["n_medicines"]),
-                  "quantity": _dense(next(ks), fc, 1)},
-    }
-
-
-def make_params(model: dict, seed: int):
-    """All weights of one configuration, float32, on the default device."""
-    if model["vitals_encoder"] != "gru":
-        raise ValueError("the harness makes GRU vitals weights only, got "
-                         f"{model['vitals_encoder']!r}")
-    fn = jax.jit(lambda k: _init(k, model))
-    return jax.block_until_ready(fn(jax_key(seed, 0x3E16)))

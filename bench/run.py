@@ -4,8 +4,11 @@
 
 Run from the root of a checkout that holds the program (``src/``) and
 ``BENCHMARK.json``. The cell names a configuration file under
-``bench/configs`` and a traffic file under ``bench/traffic``; per-layer
-metrics are readers under ``bench/metrics``, each found by its name.
+``bench/configs`` and a traffic file under ``bench/traffic``; the
+configuration names its model family, a module under ``bench/families``
+that makes its weights, gives the program's config and the reference,
+and counts its work; per-layer metrics are readers under
+``bench/metrics``. Each is found by its name.
 
 A run: checks for the chips the cell asks for (none found: exit 3, no
 result), makes the weights on the device from the seed, builds the
@@ -41,6 +44,8 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE_DIR = BENCH / ".cache" / "jax"
 OUT_DIR = BENCH / "out"
+SPEC = ROOT / "BENCHMARK.json"
+FAMILIES = BENCH / "families"
 
 sys.path.insert(0, str(BENCH))
 sys.path.insert(1, str(ROOT / "src"))
@@ -50,14 +55,40 @@ class NoChip(RuntimeError):
     """JAX found no accelerator, or fewer chips than the cell asks for."""
 
 
-def load_cell(name: str):
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+def load_family(name, dirs=(FAMILIES,)):
+    """The family module ``<dir>/<name>.py`` from the first of ``dirs``
+    that holds one; ``name`` None (a configuration that names no family)
+    or one that no directory holds is refused, with the families found."""
+    found = {}
+    for d in dirs:
+        for path in sorted(Path(d).glob("*.py")):
+            found.setdefault(path.stem, path)
+    if name not in found:
+        raise ValueError(f"no model family {name!r} (a configuration names "
+                         f"one under the key 'family'); families found: "
+                         f"{sorted(found)}")
+    mod_name = f"bench_family_{name}"
+    mod = sys.modules.get(mod_name)
+    if mod is None or Path(mod.__file__) != found[name]:
+        spec = importlib.util.spec_from_file_location(mod_name, found[name])
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod
+        spec.loader.exec_module(mod)
+    return mod
+
+
+def load_cell(name: str, spec_path: Path = SPEC, family_dirs=(FAMILIES,)):
+    """The cell ``name`` of the benchmark at ``spec_path`` (configuration
+    files lie relative to its directory), its configuration file and
+    family, traffic, and the metrics that apply to it."""
+    spec = json.loads(spec_path.read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     config = next(c for c in spec["configs"] if c["name"] == cell["config"])
-    model_file = json.loads((ROOT / config["file"]).read_text())
+    model_file = json.loads((spec_path.parent / config["file"]).read_text())
+    family = load_family(model_file.get("family"), family_dirs)
     traffic = json.loads(
         (BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
 
@@ -65,7 +96,7 @@ def load_cell(name: str):
         return name in metric.get("workloads", [name])
     e2e = [m for m in spec["end_to_end"] if applies(m)]
     per_layer = [m for m in spec["per_layer"] if applies(m)]
-    return cell, model_file, traffic, e2e, per_layer
+    return cell, model_file, family, traffic, e2e, per_layer
 
 
 def configure_jax():
@@ -104,14 +135,17 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
         chips_check: bool = True, model_override: dict | None = None,
         serving_override: dict | None = None,
         traffic_override: dict | None = None, limits: dict | None = None,
+        spec_path: Path = SPEC, family_dirs=(FAMILIES,),
         on_window=None, on_check=None) -> dict:
     """One run of one cell; returns the result object. The keyword
     arguments let a test drive every step but the look for a chip, at a
-    size a CPU can hold, and let the tools beside this file (``sweep.py``,
-    ``control.py``) read more of a run: ``on_window(win)`` after the
-    drain, ``on_check(params, model, rows, ref, got)`` after the
+    size a CPU can hold, from a benchmark and families of its own, and
+    let the tools beside this file (``sweep.py``, ``control.py``) read
+    more of a run: ``on_window(win)`` after the drain,
+    ``on_check(family, params, model, rows, ref, got)`` after the
     reference."""
-    cell, model_file, traffic, e2e, per_layer = load_cell(cell_name)
+    cell, model_file, family, traffic, e2e, per_layer = load_cell(
+        cell_name, spec_path, family_dirs)
     model = dict(model_file["model"], **(model_override or {}))
     serving = dict(model_file["serving"], **(serving_override or {}))
     traffic = dict(traffic, **(traffic_override or {}))
@@ -126,13 +160,12 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
 
     from harness import check, gen, layers, reference, serve, trace, window
     from harness.peaks import peaks
-    from harness.weights import make_params
 
     # a run on the CPU (the tests) is reduced against the v5e's peaks
     peak = peaks(dev.device_kind if chips_check else "TPU v5 lite")
     counter = serve.CompileCounter()
-    params = make_params(model, seed)
-    splits = serve.build_zoo(model)
+    params = family.make_params(model, seed)
+    splits = serve.build_zoo(family, model)
     tracer = None
     if traced:
         from repro.obs import Tracer
@@ -203,7 +236,7 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
     if on_window is not None:
         on_window(win)
 
-    ctx = layers.Context(win=win, model=model, peaks=peak,
+    ctx = layers.Context(win=win, model=model, peaks=peak, family=family,
                          spans=list(tracer.events) if tracer else [])
     memory_peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
 
@@ -223,13 +256,13 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
     gc.collect()
     rule = model_file["correct"]
     t_ref = time.perf_counter()
-    ref = reference.forward(params, model, rows,
+    ref = reference.forward(family, params, model, rows,
                             precision=rule["reference_precision"])
     got = check.stack_outputs(sample) if sample else None
     checks = check.judge(got, ref, limits or rule["limits"])
     t_ref = time.perf_counter() - t_ref
     if on_check is not None:
-        on_check(params, model, rows, ref, got)
+        on_check(family, params, model, rows, ref, got)
     checks["wrong_subset"] = {"value": wrong_subset, "limit": 0}
     checks["failed"] = {"value": n_failed, "limit": 0}
     correct = all(c["value"] is not None and c["value"] <= c["limit"]

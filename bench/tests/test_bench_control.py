@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import pytest
 
 import _setup
+import run as bench
 from harness import check, gen, reference
-from harness.weights import make_params
 
 SPEC = json.loads((_setup.BENCH.parent / "BENCHMARK.json").read_text())
 # each configuration's first cell
@@ -20,11 +20,11 @@ ROWS = 24
 
 
 def load(config):
-    entry = next(c for c in SPEC["configs"] if c["name"] == config)
-    model_file = json.loads((_setup.BENCH.parent / entry["file"]).read_text())
-    traffic = json.loads((_setup.BENCH / "traffic"
-                          / f"{CELLS[config]['traffic']}.json").read_text())
-    return model_file, traffic
+    """The configuration's file, its family and its first cell's traffic,
+    as a run loads them."""
+    _, model_file, family, traffic, _, _ = bench.load_cell(
+        CELLS[config]["name"])
+    return model_file, family, traffic
 
 
 def window_rows(model, traffic, seed):
@@ -46,13 +46,14 @@ def test_configuration_names_known_numbers(config):
 
 @pytest.mark.parametrize("config", sorted(CELLS))
 def test_bfloat16_control_fails_the_configured_limits(config):
-    model_file, traffic = load(config)
+    model_file, family, traffic = load(config)
     m, rule = model_file["model"], model_file["correct"]
-    params = make_params(m, seed=2**34 + 5)
+    params = family.make_params(m, seed=2**34 + 5)
     rows = window_rows(m, traffic, seed=2**34 + 5)
-    ref = reference.forward(params, m, rows, block=ROWS,
+    ref = reference.forward(family, params, m, rows, block=ROWS,
                             precision=rule["reference_precision"])
-    low = reference.forward(params, m, rows, block=ROWS, dtype=jnp.bfloat16,
+    low = reference.forward(family, params, m, rows, block=ROWS,
+                            dtype=jnp.bfloat16,
                             precision=rule["reference_precision"])
     checks = check.judge(low, ref, rule["limits"])
     assert any(c["value"] > c["limit"] for c in checks.values()), checks

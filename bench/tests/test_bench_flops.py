@@ -1,9 +1,11 @@
-"""FLOP and byte counts against values worked out by hand at tiny
-widths."""
+"""The emsnet_bert_gru family's FLOP and byte counts against values
+worked out by hand at tiny widths."""
 import pytest
 
 import _setup  # noqa: F401
-from harness import flops
+import run as bench
+
+flops = bench.load_family("emsnet_bert_gru")
 
 M = {"text_layers": 2, "text_hidden": 4, "text_heads": 2, "text_ffn": 8,
      "n_vitals": 3, "vitals_hidden": 2, "scene_dim": 3, "scene_hidden": 5,
@@ -15,6 +17,10 @@ def test_attention():
     assert flops.attention_flops(3, M) == 288
     # Q, K, V, O: 4 tensors x 3 x 4 floats x 4 bytes x 2 layers
     assert flops.attention_bytes(3, M) == 384
+    # the flash kernel's work, by its op name in the trace
+    assert flops.kernel_work("flash_attention", 3, M) == (288, 384)
+    with pytest.raises(KeyError, match="gmm"):
+        flops.kernel_work("gmm", 3, M)
 
 
 def test_text_encoder():

@@ -1,12 +1,14 @@
-"""The plain reference against the program's model at tiny widths, and
-the bfloat16 control against the reference."""
+"""The emsnet_bert_gru family's plain reference against the program's
+model at tiny widths, and the bfloat16 control against the reference."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import _setup
-from harness import check, reference, serve
-from harness.weights import make_params
+import run as bench
+from harness import check, reference
+
+FAMILY = bench.load_family("emsnet_bert_gru")
 
 M = dict(_setup.TINY_MODEL, max_text_len=16, vitals_len=8)
 SUBSETS = [("text",), ("vitals",), ("scene",), ("text", "vitals"),
@@ -35,12 +37,12 @@ def rows(seed, n):
 
 @pytest.fixture(scope="module")
 def params():
-    return make_params(M, seed=3)
+    return FAMILY.make_params(M, seed=3)
 
 
 def program_outputs(params, rs, use_flash):
     from repro.models import emsnet as E
-    cfg = serve.program_config(dict(M, use_flash_text=use_flash))
+    cfg = FAMILY.program_config(dict(M, use_flash_text=use_flash))
     outs = {k: [] for k in reference.OUTPUTS}
     for row in rs:
         sub = tuple(m for m in reference.MODALITIES if m in row)
@@ -55,7 +57,7 @@ def program_outputs(params, rs, use_flash):
                          ids=["einsum", "flash"])
 def test_reference_matches_program_on_every_subset(params, use_flash):
     rs = rows(0, 14)
-    ref = reference.forward(params, M, rs, block=8)
+    ref = reference.forward(FAMILY, params, M, rs, block=8)
     got = program_outputs(params, rs, use_flash)
     # both are float32 on the CPU backend: only the order of the sums
     # differs, a few units of float32 rounding on outputs of size ~1
@@ -65,8 +67,8 @@ def test_reference_matches_program_on_every_subset(params, use_flash):
 
 def test_bfloat16_control_is_far_from_reference(params):
     rs = rows(1, 14)
-    ref = reference.forward(params, M, rs, block=8)
-    low = reference.forward(params, M, rs, block=8, dtype=jnp.bfloat16)
+    ref = reference.forward(FAMILY, params, M, rs, block=8)
+    low = reference.forward(FAMILY, params, M, rs, block=8, dtype=jnp.bfloat16)
     # one bfloat16 rounding alone is 2^-9 relative; the control's gap is
     # many times the float32 program's
     assert check.output_gap(low, ref) > 100 * 2e-5
@@ -74,7 +76,7 @@ def test_bfloat16_control_is_far_from_reference(params):
 
 def test_a_wrong_head_row_is_seen(params):
     rs = rows(2, 7)
-    ref = reference.forward(params, M, rs, block=8)
+    ref = reference.forward(FAMILY, params, M, rs, block=8)
     got = {k: v.copy() for k, v in ref.items()}
     got["medicine_logits"][3, 5] += 0.05
     assert check.output_gap(got, ref) == pytest.approx(0.05, rel=1e-3)

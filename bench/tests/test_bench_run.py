@@ -17,11 +17,13 @@ TINY_LIMIT = {"output_gap": 1e-4, "mean_gap": 1e-5}
 
 @pytest.fixture(scope="module")
 def splits():
-    return serve.build_zoo(_setup.TINY_MODEL)
+    return serve.build_zoo(bench.load_family("emsnet_bert_gru"),
+                           _setup.TINY_MODEL)
 
 
 def run_with(monkeypatch, splits, *, zoo=None, rate=None):
-    monkeypatch.setattr(serve, "build_zoo", lambda model: zoo or splits)
+    monkeypatch.setattr(serve, "build_zoo",
+                        lambda family, model: zoo or splits)
     traffic = dict(_setup.TINY_TRAFFIC)
     if rate is not None:
         traffic["rate_sessions_per_s"] = rate

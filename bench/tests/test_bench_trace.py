@@ -57,9 +57,21 @@ def test_idle_gaps_by_host_activity():
 
 def test_kernel_seconds_sum_the_pallas_ops_in_the_window():
     tr = synthetic()
-    assert trace.kernel_seconds(tr) == pytest.approx(0.030)
+    assert trace.kernel_seconds(tr, "flash_kernel") == pytest.approx(0.030)
     tr.window = (15 * MS, 65 * MS)
-    assert trace.kernel_seconds(tr) == pytest.approx(0.020)
+    assert trace.kernel_seconds(tr, "flash_kernel") == pytest.approx(0.020)
+
+
+def test_kernel_seconds_count_only_the_named_kernel():
+    # a second Pallas kernel (a grouped expert matmul) beside the flash
+    # kernel, on two devices: each name gets only its own ops' time
+    tr = synthetic()
+    gmm = [("gmm", 30 * MS, 35 * MS), ("gmm", 80 * MS, 120 * MS)]
+    tr.kernels[0] += gmm
+    tr.kernels.append([("flash_kernel", 0, 4 * MS), ("gmm", 0, 1 * MS)])
+    assert trace.kernel_seconds(tr, "flash_kernel") == pytest.approx(0.034)
+    assert trace.kernel_seconds(tr, "gmm") == pytest.approx(0.026)
+    assert trace.kernel_seconds(tr, "fusion.2") == 0.0
 
 
 def test_a_kernel_is_known_by_its_custom_call_target():
