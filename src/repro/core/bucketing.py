@@ -22,13 +22,24 @@ Padding must not change the math:
 Inputs longer than ``max_bucket`` are cropped to it: vitals keep their
 most recent steps (a sliding window; NEMSIS caps at 30 per event
 anyway), text keeps its leading tokens (the valid prefix).
+
+Where the data lives decides where it is padded and stacked. Host
+(numpy) inputs are padded and stacked in numpy and stay numpy: they
+reach the device as the jitted encoder's own argument, inside its
+dispatch, with no eager device program. Inputs already on the device
+(the feature rows a tail consumes) cost one device program per pad or
+per stacked leaf. The choice is made per array, so a vitals dict may
+carry a device ``x`` beside its host ``len``.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def next_pow2(n: int) -> int:
@@ -61,6 +72,8 @@ def pad_axis(x, length: int, axis: int, pad_value=0, keep: str = "tail"):
         return x[tuple(idx)]
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, length - n)
+    if isinstance(x, np.ndarray):
+        return np.pad(x, widths, constant_values=pad_value)
     return jnp.pad(x, widths, constant_values=pad_value)
 
 
@@ -72,6 +85,8 @@ class Bucketer:
       * text  (B, S) int32  -> (B, S_b) int32, PAD=0
       * vitals (B, T, n)    -> {"x": (B, T_b, n), "len": (B,) int32}
       * anything fixed-size -> passthrough
+    Each array stays where it was (numpy in, numpy out); ``len`` is
+    always a host array.
     """
     min_bucket: int = 8
     max_buckets: Dict[str, int] = field(default_factory=dict)
@@ -98,7 +113,7 @@ class Bucketer:
             b = self.bucket(modality, T)
             self._count(modality, b)
             return {"x": pad_axis(x, b, axis=1),
-                    "len": jnp.full((x.shape[0],), min(T, b), jnp.int32)}
+                    "len": np.full((x.shape[0],), min(T, b), np.int32)}
         return x
 
     def n_buckets(self) -> int:
@@ -110,7 +125,11 @@ def stack_bucketed(payloads, batch_bucket: int):
     length) into one batch of ``batch_bucket`` rows; surplus rows are
     zero/PAD padding (zero ``len`` for masked-vitals dicts, so padded
     rows encode to the zero initial state). Returns the stacked pytree;
-    row i -> session i for the first ``len(payloads)`` rows."""
+    row i -> session i for the first ``len(payloads)`` rows.
+
+    Each leaf is stacked where its rows live: all numpy rows are written
+    into one zero-filled numpy buffer (no device operation); otherwise
+    one jitted program concatenates and pads them on the device."""
     if isinstance(payloads[0], dict):
         keys = payloads[0].keys()
         for i, p in enumerate(payloads[1:], start=1):
@@ -119,10 +138,30 @@ def stack_bucketed(payloads, batch_bucket: int):
                     f"stack_bucketed: payload 0 has keys {sorted(keys)} but "
                     f"payload {i} has {sorted(p.keys())}; refusing to drop "
                     "mismatched keys")
-        return {k: pad_axis(jnp.concatenate([p[k] for p in payloads], axis=0),
-                            batch_bucket, axis=0)
+        return {k: _stack_rows([p[k] for p in payloads], batch_bucket)
                 for k in keys}
-    x = jnp.concatenate(list(payloads), axis=0)
+    return _stack_rows(list(payloads), batch_bucket)
+
+
+def _stack_rows(rows, batch_bucket: int):
+    n = sum(r.shape[0] for r in rows)
+    if n > batch_bucket:
+        raise ValueError(f"stack_bucketed: {n} rows do not fit a batch "
+                         f"of {batch_bucket}")
+    if not all(isinstance(r, np.ndarray) for r in rows):
+        return _stack_on_device(tuple(rows), batch_bucket)
+    dtype = jax.dtypes.canonicalize_dtype(np.result_type(*rows))
+    out = np.zeros((batch_bucket,) + rows[0].shape[1:], dtype)
+    i = 0
+    for r in rows:
+        out[i:i + r.shape[0]] = r
+        i += r.shape[0]
+    return out
+
+
+@partial(jax.jit, static_argnums=1)
+def _stack_on_device(rows, batch_bucket: int):
+    x = jnp.concatenate(rows, axis=0)
     return pad_axis(x, batch_bucket, axis=0)
 
 

@@ -535,6 +535,8 @@ class EMSServeEngine:
         # per-(model, subtree) parameter counts, the flop-estimate
         # weights for FlushReport's padding-tax accounting
         self._flop_w: Dict[Tuple[str, str], float] = {}
+        # width -> the grouped tail's zero row for a missing modality
+        self._zero_rows: Dict[int, jax.Array] = {}
 
         # ---- stream policy -> deadline / eviction state
         sp = stream
@@ -826,19 +828,41 @@ class EMSServeEngine:
         Modalities no model consumes are skipped BEFORE the bucketer
         sees them, so bucket/compile statistics count served groups
         only (an unconsumed modality used to inflate the histogram the
-        bench reports)."""
+        bench reports). Returns the groups and the device operations
+        the bucketer's pads and crops issued."""
         groups = defaultdict(list)  # (modality, shape) -> [(sid, payload, nat)]
+        ops = 0
         for sid in sids:
             st = self.sessions[sid]
             for m in sorted(st.dirty):
                 if not self._consumers(m):
                     continue
-                p = self._bucketed(m, st.inputs[m])
+                x = st.inputs[m]
+                p = self._bucketed(m, x)
+                ops += self._issued(p, x)
                 shape = (tuple(p["x"].shape) if isinstance(p, dict)
                          else tuple(p.shape))
-                groups[(m, shape)].append(
-                    (st.sid, p, self._nat_len(st.inputs[m])))
-        return groups
+                groups[(m, shape)].append((st.sid, p, self._nat_len(x)))
+        return groups, ops
+
+    @staticmethod
+    def _issued(out, src=None) -> int:
+        """Device operations that made ``out``: one per device array in
+        it other than ``src`` (an input passed through unpadded). A host
+        (numpy) array took none."""
+        return sum(isinstance(a, jax.Array) and a is not src
+                   for a in jax.tree_util.tree_leaves(out))
+
+    def _stack(self, rows):
+        """``stack_bucketed`` at the rows' batch bucket. Returns the batch
+        and the device programs it issued (one per device leaf; none for
+        a batch built in numpy), and counts the batch in
+        ``engine.batches_host`` or ``engine.batches_device``."""
+        stacked = stack_bucketed(rows, self._bucket_rows(len(rows)))
+        ops = self._issued(stacked)
+        self.metrics.inc("engine.batches_device" if ops
+                         else "engine.batches_host")
+        return stacked, ops
 
     @staticmethod
     def _nat_len(x) -> int:
@@ -890,20 +914,18 @@ class EMSServeEngine:
     def _flush_encode(self, touched, sync_targets, flush_id):
         """Bucketed encode: one stacked call per (modality, bucket[,
         chunk]) per consuming model."""
-        with self.tracer.scope("flush.prep", "flush",
-                               flush_id=flush_id) as phase:
-            groups = self._encode_groups(touched)
-            # one Bucketer.fit per grouped input
-            phase.set(calls=sum(map(len, groups.values()))
-                      if self.bucketer else 0)
+        with self.tracer.scope("flush.prep", "flush", flush_id=flush_id,
+                               host=0) as phase:
+            groups, ops = self._encode_groups(touched)
+            phase.set(calls=ops)
         n_enc, useful, padded = 0, 0.0, 0.0
         for (m, _shape), items in groups.items():
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
                 with self.tracer.scope("flush.prep", "flush",
-                                       flush_id=flush_id, calls=1):
-                    stacked = stack_bucketed([p for _, p, _ in chunk],
-                                             self._bucket_rows(len(chunk)))
+                                       flush_id=flush_id) as phase:
+                    stacked, ops = self._stack([p for _, p, _ in chunk])
+                    phase.set(calls=ops, host=int(ops == 0))
                     lead = (stacked["x"] if isinstance(stacked, dict)
                             else stacked)
                     plen = lead.shape[1] if lead.ndim >= 2 else 1
@@ -924,7 +946,7 @@ class EMSServeEngine:
         ragged_mods = defaultdict(list)      # m -> [(sid, raw, nat)]
         fixed = defaultdict(list)            # (m, shape) -> [(sid, raw, nat)]
         with self.tracer.scope("flush.prep", "flush", flush_id=flush_id,
-                               calls=0):
+                               calls=0, host=0):
             for sid in touched:
                 st = self.sessions[sid]
                 for m in sorted(st.dirty):
@@ -941,7 +963,7 @@ class EMSServeEngine:
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
                 with self.tracer.scope("flush.prep", "flush",
-                                       flush_id=flush_id, calls=1):
+                                       flush_id=flush_id, calls=1, host=0):
                     packed = self.ragged.pack(m, [x for _, x, _ in chunk])
                     total = (packed["tokens"] if m == "text"
                              else packed["x"]).shape[1]
@@ -957,9 +979,9 @@ class EMSServeEngine:
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
                 with self.tracer.scope("flush.prep", "flush",
-                                       flush_id=flush_id, calls=1):
-                    stacked = stack_bucketed([x for _, x, _ in chunk],
-                                             self._bucket_rows(len(chunk)))
+                                       flush_id=flush_id) as phase:
+                    stacked, ops = self._stack([x for _, x, _ in chunk])
+                    phase.set(calls=ops, host=int(ops == 0))
                     rows = (stacked["x"] if isinstance(stacked, dict)
                             else stacked).shape[0]
                 c, u, pd = self._run_encoder_chunk(
@@ -983,11 +1005,10 @@ class EMSServeEngine:
             for c0 in range(0, len(items), self.max_coalesce):
                 chunk = items[c0:c0 + self.max_coalesce]
                 with self.tracer.scope("flush.prep", "flush",
-                                       flush_id=flush_id, calls=len(mods)):
-                    stacked = {mm: stack_bucketed(
-                                   [f[mm] for _, f in chunk],
-                                   self._bucket_rows(len(chunk)))
-                               for mm in mods}
+                                       flush_id=flush_id) as phase:
+                    stacked = self._stack_tail(
+                        {mm: [f[mm] for _, f in chunk] for mm in mods},
+                        phase)
                 with self.tracer.scope("flush.tail", "flush",
                                        flush_id=flush_id, calls=1):
                     outs = sm.tail(self.params[name], stacked)
@@ -1000,6 +1021,25 @@ class EMSServeEngine:
                     [(sid, name) for sid, _ in chunk], len(tail_outs) - 1,
                     flush_id)
         return tail_outs, to_emit, useful, padded
+
+    def _stack_tail(self, columns, phase):
+        """A tail call's input: one ``_stack`` per modality's column of
+        feature rows. Sets ``calls`` and ``host`` on the ``phase`` span."""
+        stacked, ops, host = {}, 0, 0
+        for m, rows in columns.items():
+            stacked[m], n = self._stack(rows)
+            ops += n
+            host += n == 0
+        phase.set(calls=ops, host=host)
+        return stacked
+
+    def _zero_row(self, width: int):
+        """The zero feature row a grouped tail puts in a missing
+        modality's slice: made on the device once per width."""
+        row = self._zero_rows.get(width)
+        if row is None:
+            row = self._zero_rows[width] = jnp.zeros((1, width), jnp.float32)
+        return row
 
     def _scatter_tail_rows(self, chunk, call, flush_id):
         """Re-stamp the cache entries each row of tail call ``call``
@@ -1060,13 +1100,11 @@ class EMSServeEngine:
         for c0 in range(0, len(rows), self.max_coalesce):
             chunk = rows[c0:c0 + self.max_coalesce]
             nb = self._bucket_rows(len(chunk))
-            with self.tracer.scope("flush.prep", "flush", flush_id=flush_id,
-                                   calls=len(full_mods)):
-                stacked = {
-                    m: stack_bucketed(
-                        [f.get(m, jnp.zeros((1, dims[m]), jnp.float32))
-                         for _, _, f in chunk], nb)
-                    for m in full_mods}
+            with self.tracer.scope("flush.prep", "flush",
+                                   flush_id=flush_id) as phase:
+                stacked = self._stack_tail(
+                    {m: [f.get(m, self._zero_row(dims[m]))
+                         for _, _, f in chunk] for m in full_mods}, phase)
             with self.tracer.scope("flush.tail", "flush", flush_id=flush_id,
                                    calls=1):
                 outs = full_sm.tail(self.params[full_name], stacked)
@@ -1091,10 +1129,12 @@ class EMSServeEngine:
 
         With a tracer, the ``flush`` span ``[t0, t1]`` holds disjoint
         phase spans (cat ``flush``, each with ``flush_id`` and ``calls``,
-        the device array operations it issued: each bucketer fit, stack,
-        pack, feature row slice, encoder and tail call, and tail output
-        leaf fetched once): ``flush.prep`` (model
-        selection, cache reads, pads, grouping, stacks and packs),
+        the device array operations it issued: each pad or crop of a
+        device input, stacked device leaf, pack, feature row slice,
+        encoder and tail call, and tail output leaf fetched once; a fit
+        or batch built in numpy on the host issues none): ``flush.prep``
+        (model selection, cache reads, pads, grouping, stacks and packs;
+        ``host`` counts its batches built in numpy),
         ``flush.encode`` and ``flush.tail`` (the program calls),
         ``flush.scatter`` (row slices into the cache, the rows'
         bookkeeping, and, after the sync, the one fetch of every tail
@@ -1124,7 +1164,8 @@ class EMSServeEngine:
                                                      flush_id)
 
         # ---- progressive re-fusion: batched tails per selected model
-        with tr.scope("flush.prep", "flush", flush_id=flush_id, calls=0):
+        with tr.scope("flush.prep", "flush", flush_id=flush_id, calls=0,
+                      host=0):
             tail_groups = defaultdict(list)    # model -> [(sid, feats)]
             consumed_meta: Dict[Tuple[str, str], dict] = {}
             for sid in touched:

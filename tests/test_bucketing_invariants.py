@@ -123,3 +123,75 @@ def test_pad_axis_crop_directions():
     np.testing.assert_array_equal(np.asarray(pad_axis(x, 3, axis=1,
                                                       keep="head"))[0],
                                   [0, 1, 2])
+
+
+# --------------------------------------- host batches vs device batches
+
+# (modality, natural length, length cap): padded, exactly at the bucket,
+# and cropped (text keeps its head, vitals their tail); scene is fixed-size
+HOST_CASES = [("text", 5, None), ("text", 8, None), ("text", 13, 8),
+              ("vitals", 5, None), ("vitals", 8, None), ("vitals", 13, 8),
+              ("scene", None, None)]
+
+
+def _rows(modality, n, seed=0):
+    rng = np.random.default_rng(seed)
+    if modality == "text":
+        return [rng.integers(1, 100, (1, n)).astype(np.int32)
+                for _ in range(3)]
+    if modality == "vitals":
+        return [rng.normal(size=(1, n, 3)).astype(np.float32)
+                for _ in range(3)]
+    return [rng.integers(0, 2, (1, 3)).astype(np.float32) for _ in range(3)]
+
+
+def _leaves(tree):
+    return jax.tree_util.tree_leaves(tree)
+
+
+@pytest.mark.parametrize("modality,n,cap", HOST_CASES)
+def test_host_fit_and_stack_stay_numpy_and_equal_the_device_path(
+        modality, n, cap):
+    """Numpy payloads are padded, cropped and stacked in numpy, with no
+    transfer to the device (so no device operation), and give the device
+    path's bytes, dtypes and keys."""
+    b = Bucketer(min_bucket=4, max_buckets={} if cap is None
+                 else {modality: cap})
+    rows = _rows(modality, n)
+    with jax.transfer_guard_host_to_device("disallow"):
+        fitted = [b.fit(modality, x) for x in rows]
+        host = stack_bucketed(fitted, 8)
+    dev = stack_bucketed([b.fit(modality, jnp.asarray(x)) for x in rows], 8)
+    assert all(type(a) is np.ndarray for a in _leaves(fitted) + _leaves(host))
+    assert jax.tree_util.tree_structure(host) == \
+        jax.tree_util.tree_structure(dev)
+    for h, d in zip(_leaves(host), _leaves(dev)):
+        assert h.dtype == d.dtype and h.shape == d.shape
+        np.testing.assert_array_equal(h, np.asarray(d))
+
+
+@pytest.mark.parametrize("modality,n,cap", HOST_CASES)
+def test_device_stack_equals_concatenate_then_pad(modality, n, cap):
+    """Device rows stack in one program to what an eager concatenate
+    followed by a pad of the surplus rows gives."""
+    b = Bucketer(min_bucket=4, max_buckets={} if cap is None
+                 else {modality: cap})
+    fitted = [b.fit(modality, jnp.asarray(x)) for x in _rows(modality, n)]
+    got = stack_bucketed(fitted, 8)
+    for k, g in (got.items() if isinstance(got, dict) else [(None, got)]):
+        col = [p[k] if k is not None else p for p in fitted]
+        want = jnp.concatenate([jnp.asarray(c) for c in col], axis=0)
+        want = jnp.pad(want, [(0, 8 - want.shape[0])]
+                       + [(0, 0)] * (want.ndim - 1))
+        # `len` is a host leaf whatever the payload's home; x stacks on
+        # the device
+        assert isinstance(g, np.ndarray if k == "len" else jax.Array)
+        assert g.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
+
+
+@pytest.mark.parametrize("host", [False, True], ids=["device", "host"])
+def test_stack_bucketed_refuses_more_rows_than_the_bucket(host):
+    rows = [np.ones((1, 4), np.int32)] * 3
+    with pytest.raises(ValueError, match="do not fit"):
+        stack_bucketed(rows if host else [jnp.asarray(r) for r in rows], 2)

@@ -593,11 +593,15 @@ PHASES = ("flush.prep", "flush.encode", "flush.scatter", "flush.tail",
           "flush.sync", "flush.emit")
 
 
-def _two_session_flush(zoo_models, *, ragged, tracer, tail_outs=None):
+def _two_session_flush(zoo_models, *, ragged, tracer, tail_outs=None,
+                       host=False):
     """s0 sends text and vitals, s1 text; one flush takes all three.
     Where ``tail_outs`` is a list, every tail call's whole output is
-    appended to it."""
+    appended to it. ``host`` sends the payloads as numpy arrays, as a
+    deployment's network front end hands them over."""
     cfg, splits, shared, params, payloads = zoo_models
+    if host:
+        payloads = {m: np.asarray(x) for m, x in payloads.items()}
     if tail_outs is not None:
         def spy(tail):
             def call(p, feats):
@@ -641,12 +645,14 @@ def test_flush_phases_are_disjoint_inside_the_flush_span(zoo_models,
     assert all(e.args["flush_id"] == rep.flush_id for e in waits)
 
 
+@pytest.mark.parametrize("host", [False, True], ids=["device", "host"])
 @pytest.mark.parametrize("ragged", [False, True])
-def test_flush_phase_calls_match_a_hand_count(zoo_models, ragged):
+def test_flush_phase_calls_match_a_hand_count(zoo_models, ragged, host):
     eng, rep = _two_session_flush(zoo_models, ragged=ragged,
-                                  tracer=Tracer())
+                                  tracer=Tracer(), host=host)
     leaves = len(jax.tree_util.tree_leaves(rep.predictions[0].outputs))
     calls = {}
+    prep = [e for e in eng.tracer.events if e.name == "flush.prep"]
     for e in eng.tracer.events:
         if e.name in PHASES:
             calls[e.name] = calls.get(e.name, 0) + e.args["calls"]
@@ -657,15 +663,50 @@ def test_flush_phase_calls_match_a_hand_count(zoo_models, ragged):
         # over both sessions: a stack per modality of the full model
         want = {"flush.prep": 2 + 3, "flush.encode": 2, "flush.tail": 1,
                 "flush.scatter": 3 + rep.n_tail_calls * leaves}
+        built_on_host = 0
+    elif host:
+        # fits and encoder batches are numpy work; only the stacks of
+        # device feature rows issue a program: the text+vitals tail (2)
+        # and the text tail (1)
+        want = {"flush.prep": 2 + 1, "flush.encode": 2, "flush.tail": 2,
+                "flush.scatter": 3 + rep.n_tail_calls * leaves}
+        built_on_host = 2
     else:
-        # 3 bucketer fits, a stack per encoder chunk (text, vitals), then
+        # 3 bucketer pads, a stack per encoder chunk (text, vitals), then
         # the text+vitals tail (2 stacks) and the text tail (1 stack)
         want = {"flush.prep": 3 + 2 + 2 + 1, "flush.encode": 2,
                 "flush.tail": 2,
                 "flush.scatter": 3 + rep.n_tail_calls * leaves}
+        built_on_host = 0
     assert calls == dict(want, **{"flush.sync": 0, "flush.emit": 0})
     assert calls["flush.encode"] == rep.n_encoder_calls
     assert calls["flush.tail"] == rep.n_tail_calls
+    assert sum(e.args["host"] for e in prep) == built_on_host
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_host_payload_flush_equals_device_payload_flush(zoo_models, ragged):
+    """The same flush over numpy payloads (batches built on the host) and
+    over device payloads (pads and stacks on the device) runs the same
+    programs on the same values: bit-identical predictions. The batch
+    counters split the batches by where they were built."""
+    dev_eng, dev = _two_session_flush(zoo_models, ragged=ragged,
+                                      tracer=None)
+    host_eng, host = _two_session_flush(zoo_models, ragged=ragged,
+                                        tracer=None, host=True)
+    assert [(p.sid, p.model, p.step) for p in host.predictions] == \
+        [(p.sid, p.model, p.step) for p in dev.predictions]
+    for a, b in zip(host.predictions, dev.predictions):
+        assert set(a.outputs) == set(b.outputs)
+        for k in a.outputs:
+            np.testing.assert_array_equal(a.outputs[k], b.outputs[k])
+    # bucketed: text and vitals encoder batches, then 3 tail stacks;
+    # ragged: packs instead of encoder batches, then 3 grouped-tail stacks
+    enc, tails = (0, 3) if ragged else (2, 3)
+    counts = lambda eng: (eng.metrics.get("engine.batches_host"),
+                          eng.metrics.get("engine.batches_device"))
+    assert counts(host_eng) == (enc, tails)
+    assert counts(dev_eng) == (0, enc + tails)
 
 
 @pytest.mark.parametrize("ragged", [False, True])
